@@ -24,6 +24,7 @@ def inner():
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.configs import ARCHS, reduced_config
+    from repro.launch.mesh import make_auto_mesh
     from repro.launch.steps import make_fl_train_step
     from repro.models import build_model
     from repro.sharding import param_specs
@@ -36,7 +37,7 @@ def inner():
 
     cfg = dataclasses.replace(reduced_config(ARCHS["qwen3-1.7b"]),
                               d_model=256, n_heads=4, n_kv_heads=2)
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_auto_mesh((2, 2, 2), ("pod", "data", "model"))
     print(f"devices={len(jax.devices())} mesh={dict(mesh.shape)} "
           f"aggregation={args.aggregation}")
 

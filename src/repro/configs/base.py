@@ -195,8 +195,9 @@ class FLConfig:
             (~4x)               (grad tgts)    ``safl_fold_q8``
     q4      Dq/2 + 4Dq/B        residual +     ``safl_aggregate_q4`` /
             (~8x)               stoch. round   ``safl_fold_q4``
-    topk    5nk + 4nk/B         residual incl. ``safl_aggregate_topk`` /
-            (~8x @ 10%)         dropped coords ``safl_fold_topk``
+    topk    5nk + 4nk/B         residual incl. none: XLA scatter of
+            (~8x @ 10%)         dropped coords ``kernels.ref`` on every
+                                               backend
     ======  ==================  =============  ==========================
 
     (B = ``quant_block``; nk = ``ceil(topk_frac * d)`` rounded up to a
@@ -209,7 +210,7 @@ class FLConfig:
     unbiased, so the error-feedback residual telescopes.  ``topk``: only
     the nk largest-|coordinate| entries travel, as (int32 index, int8
     value) pairs; the residual carries the dropped coordinates in full,
-    and the server aggregates through a fused
+    and the server aggregates through an XLA
     gather-dequant-scatter-accumulate without materializing dense rows.
     ``topk`` is *gradient-only*: fedavg / fedasync upload weights, and a
     sparse weight average would zero untransmitted coordinates.
@@ -512,8 +513,8 @@ class FLConfig:
         assert self.local_batch_size >= 1
         # quantized channel: one scale per quant_block lanes.  Tiny blocks
         # would make the scale overhead rival the int8 payload, and the
-        # fused Pallas kernels tile scales per BLOCK_D=2048 lanes, so the
-        # granule must be a power of two dividing 2048
+        # fused Pallas kernels tile scales in whole power-of-two lane
+        # tiles, so the granule must be a power of two
         assert (8 <= self.quant_block <= 2048
                 and self.quant_block & (self.quant_block - 1) == 0), \
             "quant_block must be a power of two in [8, 2048]"

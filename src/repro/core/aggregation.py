@@ -227,7 +227,8 @@ class FlatServer:
     Backend (see :func:`repro.kernels.safl_agg.default_backend`): the
     compiled Pallas kernels on TPU, the jnp oracle (same math, XLA-fused)
     on CPU; ``pallas_interpret`` forces the kernel bodies through the
-    interpreter for validation.
+    interpreter for validation.  The top-k wire is the exception: its
+    reduction and fold are the XLA scatter on every backend (below).
 
     Modes: fedsgd / fedavg / fedbuff / fedopt / sdga / fedasync.  The
     weight-input vector ``wvec`` is per-mode: unit weights (fedsgd), data
@@ -257,10 +258,14 @@ class FlatServer:
     fused unpack-dequant kernels (:func:`safl_aggregate_q4` et al.), and
     ``"topk"`` consumes the sparse ``(idx int32 (K, nk), qv int8 (K, nk),
     scales (K, nk/qblock))`` triple (:class:`repro.core.flatbuf.TopkBuffer`
-    views) through a fused gather-dequant-scatter-accumulate — the server
-    never materializes a dense (K, D) buffer.  ``topk`` is gradient-only:
-    the weight-upload modes (fedavg, fedasync) are rejected because a
-    sparse weight average would zero every untransmitted coordinate.
+    views) through the XLA scatter-accumulate of :mod:`repro.kernels.ref`
+    (:func:`~repro.kernels.ref.topk_weighted_sum_ref` /
+    :func:`~repro.kernels.ref.fold_topk_ref`) on EVERY backend — Pallas
+    TPU has no scatter lowering, so there is no topk kernel; the server
+    still never materializes a dense (K, D) buffer.  ``topk`` is
+    gradient-only: the weight-upload modes (fedavg, fedasync) are
+    rejected because a sparse weight average would zero every
+    untransmitted coordinate.
 
     ``mesh`` (a 1-D "pod" mesh, :func:`repro.sharding.flat.make_pod_mesh`,
     or the 2-D (edge, pod) mesh of
@@ -343,10 +348,16 @@ class FlatServer:
                 f"wire='topk' is gradient-only; mode={mode} uploads weights"
         qb = qblock or _k.QBLOCK
         if (quantized or q4) and use_pallas:
-            # the q8/q4 Pallas kernels tile scales as (K, block_d/qblock);
-            # the xla streaming path has no tiling constraint
-            assert bd % qb == 0, \
-                f"block_d={bd} must be a multiple of qblock={qb}"
+            # the q8/q4 kernels read (block_d/qblock, K) scale tiles, and
+            # a compiled tile needs 8 scale rows: widen the default tile
+            # for coarse qblocks (the xla path has no tiling constraint)
+            bd = block_d or max(bd, 8 * qb)
+            if interpret:
+                assert bd % qb == 0, \
+                    f"block_d={bd} must be a multiple of qblock={qb}"
+            else:
+                _k.check_tiling(bd, qb)
+        self.block_d = bd  # lanes per kernel grid step
         self.mesh = mesh if _shflat.mesh_size(mesh) > 1 else None
 
         # external_discount: an adaptive scheduling policy
@@ -407,13 +418,7 @@ class FlatServer:
                     g = _ref.weighted_sum_q4_ref(qp, scales, w, qb)
             elif topk:
                 idx, qv, scales = buf_l
-                if use_pallas:
-                    g = _k.safl_aggregate_topk(
-                        idx, qv, scales, w, d, qblock=qb, block_d=bd,
-                        interpret=interpret)
-                else:
-                    g = _ref.topk_weighted_sum_ref(idx, qv, scales, w, d,
-                                                   qb)
+                g = _ref.topk_weighted_sum_ref(idx, qv, scales, w, d, qb)
             elif use_pallas:
                 g = _k.safl_aggregate(buf_l, w, mode="sum", block_d=bd,
                                       interpret=interpret)
@@ -503,17 +508,6 @@ class FlatServer:
             wsum = jnp.maximum(jnp.sum(w), 1e-12)
             return _ref.weighted_sum_q4_ref(qp, scales, w / wsum, qb)[:d]
 
-        def topk_sum(buf, w):
-            """Unnormalized weighted scatter-sum of the sparse rows ->
-            (d,) f32 (the fused gather-dequant-scatter kernel on the
-            Pallas backends; the server never materializes a dense row)."""
-            idx, qv, scales = buf
-            if use_pallas:
-                return _k.safl_aggregate_topk(
-                    idx, qv, scales, w, d, qblock=qb, block_d=bd,
-                    interpret=interpret)
-            return _ref.topk_weighted_sum_ref(idx, qv, scales, w, d, qb)
-
         def _step(params, buf, wvec, opt):
             p0 = params.astype(jnp.float32)
             wmass = None
@@ -540,7 +534,7 @@ class FlatServer:
                 # every topk mode reduces through the one scatter-sum +
                 # the shared _from_sums step body (gradient targets only)
                 w = discounted(wvec)
-                gsum = topk_sum(buf, w)
+                gsum = _ref.topk_weighted_sum_ref(*buf, w, d, qb)
                 new, new_opt = _from_sums(params, gsum, jnp.sum(w), opt)
             elif mode in ("fedsgd", "fedavg", "fedbuff", "fedasync"):
                 kmode = {"fedavg": "avg", "fedasync": "mix"}.get(mode,
@@ -733,13 +727,8 @@ class FlatServer:
             def _fold(bank, idx_row, qv_row, s_row, ridx, w, beta):
                 row = jax.lax.dynamic_slice(
                     bank, (ridx, jnp.int32(0)), (1, bank.shape[1]))[0]
-                if use_pallas:
-                    folded = _k.safl_fold_topk(
-                        row, idx_row, qv_row, s_row, w,
-                        qblock=qb, block_d=bd, interpret=interpret)
-                else:
-                    folded = _ref.fold_topk_ref(row, idx_row, qv_row,
-                                                s_row, w, qb)
+                folded = _ref.fold_topk_ref(row, idx_row, qv_row, s_row, w,
+                                            qb)
                 return jax.lax.dynamic_update_slice(
                     bank, folded[None], (ridx, jnp.int32(0)))
         else:
@@ -751,7 +740,10 @@ class FlatServer:
                         row, vec, w, beta if fold_beta else 1.0,
                         block_d=bd, interpret=interpret)
                 elif fold_beta:
-                    folded = _ref.fold_ref(row, vec, w, beta)
+                    # fedasync: beta == 1 - w, in the buffered oracle's
+                    # explicit one-product order
+                    del beta
+                    folded = _ref.mix_ref(row, vec, w)
                 else:
                     folded = _ref.fold_ref(row, vec, w)
                 return jax.lax.dynamic_update_slice(
@@ -764,6 +756,9 @@ class FlatServer:
         #: :attr:`fold_compile_count`).  Payload is (vec,) f32,
         #: (q_row, s_row) on the q8/q4 channels, or the sparse
         #: (idx_row, qv_row, s_row) triple on topk.
+        if self.mesh is not None:
+            # one bank row per mesh shard, folded where it lives
+            _fold = _shflat.rowwise_fold(self.mesh, _fold)
         self.fold_program = jax.jit(_fold, donate_argnums=(0,))
 
         pod_bank_reduce = (_shflat.podwise_bank_sums(self.mesh)
@@ -793,7 +788,13 @@ class FlatServer:
             upd = new.astype(jnp.float32) - p0
             metrics = {"update_norm": jnp.sqrt(jnp.sum(jnp.square(upd))),
                        "weight_sum": wsum}
-            return new, new_opt, metrics, jnp.zeros_like(bank)
+            zeroed = jnp.zeros_like(bank)
+            if self.mesh is not None:
+                # keep the zeroed bank's rows on their shards (unpinned,
+                # the partitioner may hand back a replicated bank)
+                zeroed = jax.lax.with_sharding_constraint(
+                    zeroed, _shflat.row_sharding(self.mesh))
+            return new, new_opt, metrics, zeroed
 
         # the bank is always donated: the fused zero-after-read output
         # reuses its memory, which is what AccumBuffer.release recycles
@@ -886,23 +887,17 @@ class FlatServer:
         guard: must stay 1 across rounds).  Counts whichever channel ran:
         the buffered step if it ever compiled, else the max over the
         streaming fold / finalize programs."""
-        try:
-            n = int(self._fn._cache_size())
-            if n > 0:
-                return n
-            return max(int(self.fold_program._cache_size()),
-                       int(self._finalize_fn._cache_size()))
-        except AttributeError:  # pragma: no cover - older/newer jax
-            return -1
+        n = self._fn._cache_size()
+        if n > 0:
+            return n
+        return max(self.fold_program._cache_size(),
+                   self._finalize_fn._cache_size())
 
     @property
     def fold_compile_count(self) -> int:
         """Compilations of the streaming fold program alone (must stay 1
         across every upload of a run — ridx/w/beta are traced)."""
-        try:
-            return int(self.fold_program._cache_size())
-        except AttributeError:  # pragma: no cover - older/newer jax
-            return -1
+        return self.fold_program._cache_size()
 
 
 # ---------------------------------------------------------------------------

@@ -387,8 +387,9 @@ class FLEngine:
         # persist alongside it — the counterpart of ClientState.params on
         # the sequential path.
         self._client_flats: Optional[List[jax.Array]] = None
-        # batched wave program of the last resolved (impl, mesh) combo —
-        # obs.profile.engine_compile_log tracks its compile count
+        # batched client program of the last run — the semi-async wave of
+        # the resolved (impl, mesh) combo, or the sync round (one wave of
+        # K clients) — obs.profile.engine_compile_log tracks its compiles
         self._wave_fn = None
         # wall-clock seconds spent inside run() (obs folds/sec gauge)
         self.wall_run_s = 0.0
@@ -936,6 +937,7 @@ class FLEngine:
             round_fn = make_batched_local_train(
                 self.apply_fn, self.kind, target, cfg.local_epochs,
                 mesh=self._mesh)
+            self._wave_fn = round_fn
         now = 0.0
         for _ in range(n_rounds):
             active = self.rng.choice(len(self.clients), cfg.k,

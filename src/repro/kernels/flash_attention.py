@@ -51,8 +51,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, hd: int,
         m, l, acc = carry
         idx = (pl.dslice(0, 1), pl.dslice(ki * block_k, block_k),
                pl.dslice(0, 1), pl.dslice(0, hd))
-        k = pl.load(k_ref, idx).astype(jnp.float32).reshape(block_k, hd)
-        v = pl.load(v_ref, idx).astype(jnp.float32).reshape(block_k, hd)
+        k = k_ref[idx].astype(jnp.float32).reshape(block_k, hd)
+        v = v_ref[idx].astype(jnp.float32).reshape(block_k, hd)
         s = q @ k.T  # (BLOCK_Q, BLOCK_K)
         if causal:
             k_pos = ki * block_k + jax.lax.broadcasted_iota(

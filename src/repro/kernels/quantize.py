@@ -81,16 +81,19 @@ def _resolve_backend(interpret: bool | None) -> str:
     return "pallas_interpret" if interpret else "pallas"
 
 
+# The per-row scales travel as an (R, 1) column: Mosaic refuses a rank-1
+# (ROWS,) block that spans fewer than 128 rows.
 def _quant_kernel(x_ref, q_ref, s_ref):
     x = x_ref[...].astype(jnp.float32)  # (ROWS, BLOCK)
-    scale = jnp.maximum(jnp.max(jnp.abs(x), axis=-1) / 127.0, 1e-12)
-    q = jnp.clip(jnp.round(x / scale[:, None]), -127, 127)
+    scale = jnp.maximum(
+        jnp.max(jnp.abs(x), axis=-1, keepdims=True) / 127.0, 1e-12)
+    q = jnp.clip(jnp.round(x / scale), -127, 127)
     q_ref[...] = q.astype(jnp.int8)
     s_ref[...] = scale
 
 
 def _dequant_kernel(q_ref, s_ref, o_ref):
-    o_ref[...] = q_ref[...].astype(jnp.float32) * s_ref[...][:, None]
+    o_ref[...] = q_ref[...].astype(jnp.float32) * s_ref[...]
 
 
 def quantize_int8(x: jax.Array, rows: int = ROWS,
@@ -110,12 +113,12 @@ def quantize_int8(x: jax.Array, rows: int = ROWS,
         grid=(Rp // rows,),
         in_specs=[pl.BlockSpec((rows, B), lambda i: (i, 0))],
         out_specs=(pl.BlockSpec((rows, B), lambda i: (i, 0)),
-                   pl.BlockSpec((rows,), lambda i: (i,))),
+                   pl.BlockSpec((rows, 1), lambda i: (i, 0))),
         out_shape=(jax.ShapeDtypeStruct((Rp, B), jnp.int8),
-                   jax.ShapeDtypeStruct((Rp,), jnp.float32)),
+                   jax.ShapeDtypeStruct((Rp, 1), jnp.float32)),
         interpret=backend == "pallas_interpret",
     )(x)
-    return q[:R], s[:R]
+    return q[:R], s[:R, 0]
 
 
 def dequantize_int8(q: jax.Array, scales: jax.Array, rows: int = ROWS,
@@ -134,11 +137,11 @@ def dequantize_int8(q: jax.Array, scales: jax.Array, rows: int = ROWS,
         _dequant_kernel,
         grid=(Rp // rows,),
         in_specs=[pl.BlockSpec((rows, B), lambda i: (i, 0)),
-                  pl.BlockSpec((rows,), lambda i: (i,))],
+                  pl.BlockSpec((rows, 1), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((rows, B), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Rp, B), jnp.float32),
         interpret=backend == "pallas_interpret",
-    )(q, scales)
+    )(q, scales[:, None])
     return out[:R]
 
 

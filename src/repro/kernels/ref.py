@@ -54,6 +54,19 @@ def fold_ref(acc: jax.Array, vec: jax.Array, w, beta=1.0) -> jax.Array:
             + jnp.asarray(w, jnp.float32) * vec.astype(jnp.float32))
 
 
+def mix_ref(acc: jax.Array, vec: jax.Array, a) -> jax.Array:
+    """One fedasync mix step acc <- (1 - a) acc + a vec, written as
+    acc + a (vec - acc).  With one product there is one way to contract
+    it into an FMA, so the streaming fold program and the buffered
+    :func:`fedasync_rates_flat_ref` recursion round identically.  The
+    two-product form ``beta*acc + a*vec`` lets XLA's CPU emitter fuse
+    either product, and it picks differently in the two programs (a
+    one-ulp drift)."""
+    acc = acc.astype(jnp.float32)
+    return acc + jnp.asarray(a, jnp.float32) * (vec.astype(jnp.float32)
+                                                 - acc)
+
+
 def fold_q8_ref(acc: jax.Array, q_row: jax.Array, s_row: jax.Array,
                 w, qblock: int, beta=1.0) -> jax.Array:
     """Streaming fold of one quantized upload row: blockwise dequantize
@@ -71,10 +84,11 @@ def fedasync_rates_flat_ref(updates: jax.Array, rates: jax.Array,
 
     K per-update mixes p <- (1 - a_i) p + a_i u_i decompose into a
     foldable pair: S accumulates a_i u_i prod_{j>i}(1 - a_j) one row at
-    a time (exactly the :func:`fold_ref` recursion with beta = 1 - a_i,
-    w = a_i) and P = prod_i (1 - a_i), with the final model P p + S.
-    This is the buffered oracle the streaming channel is bit-exact
-    against: both run the identical fold recursion, unlike the
+    a time (the :func:`fold_ref` recursion with beta = 1 - a_i, w = a_i,
+    computed as :func:`mix_ref`) and P = prod_i (1 - a_i), with the
+    final model P p + S.  This is the buffered oracle the streaming
+    channel is bit-exact against: both run the identical
+    :func:`mix_ref` recursion, unlike the
     coefficient-einsum form (``fedasync_flat_ref``), whose reduction
     order differs.  Returns (mixed, weight_sum = 1 - P).
     """
@@ -83,7 +97,7 @@ def fedasync_rates_flat_ref(updates: jax.Array, rates: jax.Array,
 
     def body(i, sp):
         s, prod = sp
-        return (1.0 - a[i]) * s + a[i] * u[i], prod * (1.0 - a[i])
+        return mix_ref(s, u[i], a[i]), prod * (1.0 - a[i])
 
     s, prod = jax.lax.fori_loop(
         0, a.shape[0], body,
@@ -530,7 +544,8 @@ def topk_weighted_sum_ref(idx: jax.Array, qv: jax.Array,
     (mode="drop"), so short uploads cost nothing.  The sum runs as K
     sequential row scatters so the floating-point accumulation order
     matches the streaming channel's fold-at-ingest chain on the same
-    rows — the dense row is never materialized per upload.
+    rows — the dense row is never materialized per upload.  This is the
+    server's topk reduction on every backend, not only an oracle.
     """
     w = weights.astype(jnp.float32)
     vals = dequant_topk_ref(qv, scales, qblock)  # (K, nk)
@@ -545,8 +560,9 @@ def topk_weighted_sum_ref(idx: jax.Array, qv: jax.Array,
 def fold_topk_ref(acc: jax.Array, idx: jax.Array, qv: jax.Array,
                   s_row: jax.Array, w, qblock: int, beta=1.0) -> jax.Array:
     """One streaming fold of a sparse upload: acc <- beta*acc +
-    w * scatter(dequant(qv), idx).  Oracle for
-    kernels.safl_agg.safl_fold_topk; padding coords (idx == d) drop."""
+    w * scatter(dequant(qv), idx).  The server's topk fold on every
+    backend (Pallas TPU has no scatter lowering); padding coords
+    (idx == d) drop."""
     vals = dequant_topk_ref(qv, s_row, qblock)
     base = jnp.asarray(beta, jnp.float32) * acc.astype(jnp.float32)
     return base.at[idx].add(jnp.asarray(w, jnp.float32) * vals,

@@ -31,11 +31,24 @@ Kernels:
     f32 absmax scale per QBLOCK lanes (:mod:`repro.kernels.quantize` wire
     format), and each grid step fuses blockwise dequantize into the
     reduction — the K x D read is 4x fewer HBM bytes than the f32 buffer,
-    which is exactly the memory-bound large-D regime.
+    which is exactly the memory-bound large-D regime.  ``*_q4`` do the
+    same over packed two-nibbles-per-byte rows.
 
-TPU sizing: BLOCK_D = 2048 lanes x K<=64 buffered updates x 4B = 512 KiB of
-VMEM per tile — comfortably inside the ~16 MiB v5e VMEM with double
-buffering.  The weight vector sits in SMEM (scalar-prefetch style, tiny).
+The top-k sparse wire has no kernel: its reduction scatters, Pallas TPU
+has no scatter lowering, and a tile-rebased scatter would stage the whole
+(K, nk) payload in VMEM at every grid step.  The server runs the XLA
+scatter of :mod:`repro.kernels.ref` for it on every backend.
+
+TPU sizing: BLOCK_D = 4096 lanes x K<=64 buffered updates x 4B = 1 MiB of
+VMEM per f32 tile — inside the 16 MiB scoped v5e VMEM with double
+buffering.  The quantized kernels read their scales *transposed*, as
+(Dq/qblock, K) with a (BLOCK_D/qblock, K) block: Mosaic needs the block's
+second-minor dim to be a multiple of 8 and its minor dim a multiple of
+128 or the full dim, and K is the full dim.  So BLOCK_D / qblock must be
+a multiple of 8 (8 x 512 = 4096 lanes at the default qblock).
+:func:`check_tiling` states the rule; the compile-only tests
+(``tests/test_tpu_compile.py``) hold every kernel to it at ResNet-18
+width.
 
 Backend selection (:func:`default_backend`): compiled Pallas on TPU,
 interpret-mode Pallas or the jnp oracle (:mod:`repro.kernels.ref`) on CPU —
@@ -50,10 +63,11 @@ import os
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.quantize import BLOCK as QBLOCK
 
-BLOCK_D = 2048
+BLOCK_D = 4096
 
 # discount: how the (K,) weight-input vector becomes reduction weights
 #   "none" — use as-is (unit / data-size weights)
@@ -110,6 +124,13 @@ def _weights(w, alpha: float, discount: str):
     return w
 
 
+def _matvec(w, u):
+    """(K,) weights x (K, BD) tile -> (BD,) weighted row sum.  A
+    broadcast-multiply + sublane reduce: Mosaic has no lowering for the
+    1-D ``einsum("k,kd->d")`` dot."""
+    return jnp.sum(w[:, None] * u, axis=0)
+
+
 def _agg_kernel(w_ref, u_ref, p_ref, o_ref, *, server_lr: float,
                 mode: str, alpha: float, discount: str):
     """One (K, BLOCK_D) tile: o = p - lr * (w @ u)/sum(w)  (fedsgd),
@@ -121,11 +142,11 @@ def _agg_kernel(w_ref, u_ref, p_ref, o_ref, *, server_lr: float,
     u = u_ref[...].astype(jnp.float32)  # (K, BLOCK_D)
     if mode == "mix":
         p = p_ref[...].astype(jnp.float32)
-        g = jnp.einsum("k,kd->d", w, u)
+        g = _matvec(w, u)
         o_ref[...] = ((1.0 - jnp.sum(w)) * p + g).astype(o_ref.dtype)
         return
     wsum = jnp.maximum(jnp.sum(w), 1e-12)
-    g = jnp.einsum("k,kd->d", w, u) / wsum
+    g = _matvec(w, u) / wsum
     if mode == "fedsgd":
         p = p_ref[...].astype(jnp.float32)
         o_ref[...] = (p - server_lr * g).astype(o_ref.dtype)
@@ -195,7 +216,7 @@ def _avg_kernel(w_ref, u_ref, o_ref, *, server_lr: float, mode: str,
     del server_lr
     w = _weights(w_ref[...], alpha, discount)
     u = u_ref[...].astype(jnp.float32)
-    g = jnp.einsum("k,kd->d", w, u)
+    g = _matvec(w, u)
     if mode != "sum":  # "avg" normalizes; "sum" is the per-shard partial
         g = g / jnp.maximum(jnp.sum(w), 1e-12)
     o_ref[...] = g.astype(o_ref.dtype)
@@ -247,10 +268,10 @@ def safl_fold(acc: jax.Array, vec: jax.Array, w, beta=1.0,
 
 def _fold_q8_kernel(s_ref, a_ref, q_ref, sc_ref, o_ref, *, qblock: int):
     """Streaming fold of one quantized row tile: blockwise dequantize the
-    (BLOCK_D,) int8 slice in VMEM, then o = beta*a + w*u."""
-    BD = q_ref.shape[0]
-    u = (q_ref[...].astype(jnp.float32).reshape(BD // qblock, qblock)
-         * sc_ref[...][:, None]).reshape(BD)
+    (1, BLOCK_D) int8 slice in VMEM, then o = beta*a + w*u.  The row runs
+    as a 2-D (1, D) array: Mosaic cannot split a rank-1 tile into
+    (BLOCK_D/qblock, qblock) scale groups."""
+    u = _dequant_tile(q_ref[...], sc_ref[...], qblock)
     o_ref[...] = s_ref[0] * a_ref[...].astype(jnp.float32) + s_ref[1] * u
 
 
@@ -271,21 +292,21 @@ def safl_fold_q8(acc: jax.Array, q_row: jax.Array, scales_row: jax.Array,
     Dp = Dq + pad
     sw = jnp.stack([jnp.asarray(beta, jnp.float32),
                     jnp.asarray(w, jnp.float32)])
-    vec_spec = pl.BlockSpec((block_d,), lambda i: (i,))
+    row_spec = pl.BlockSpec((1, block_d), lambda i: (0, i))
     out = pl.pallas_call(
         functools.partial(_fold_q8_kernel, qblock=qblock),
         grid=(Dp // block_d,),
         in_specs=[
             pl.BlockSpec((2,), lambda i: (0,)),
-            vec_spec,
-            vec_spec,
-            pl.BlockSpec((block_d // qblock,), lambda i: (i,)),
+            row_spec,
+            row_spec,
+            _col_scale_spec(block_d, qblock),
         ],
-        out_specs=vec_spec,
-        out_shape=jax.ShapeDtypeStruct((Dp,), jnp.float32),
+        out_specs=row_spec,
+        out_shape=jax.ShapeDtypeStruct((1, Dp), jnp.float32),
         interpret=interpret,
-    )(sw, acc, q_row, scales_row)
-    return out[:Dq]
+    )(sw, acc[None], q_row[None], scales_row[:, None])
+    return out[0, :Dq]
 
 
 # ---------------------------------------------------------------------------
@@ -309,7 +330,7 @@ def _sdga_kernel(tau_ref, u_ref, p_ref, m_ref, e_ref,
     w = _weights(tau_ref[...], alpha, discount)
     u = u_ref[...].astype(jnp.float32)
     wsum = jnp.maximum(jnp.sum(w), 1e-12)
-    g = jnp.einsum("k,kd->d", w, u) / wsum
+    g = _matvec(w, u) / wsum
     m_new = momentum * m_ref[...].astype(jnp.float32) + g
     p = p_ref[...].astype(jnp.float32)
     e = e_ref[...].astype(jnp.float32)
@@ -368,11 +389,34 @@ def sdga_aggregate(updates: jax.Array, staleness: jax.Array,
 # ---------------------------------------------------------------------------
 
 
-def _dequant_tile(q, s, qblock: int):
-    """(K, BD) int8 tile + (K, BD/qblock) scales -> (K, BD) f32 in VMEM."""
+def _scale_spec(block_d: int, qblock: int, k: int) -> pl.BlockSpec:
+    """Block of the transposed (Dq/qblock, K) scale array that grid step
+    i reads: (block_d/qblock, K) — see the module docstring for why the
+    scales travel transposed."""
+    return pl.BlockSpec((block_d // qblock, k), lambda i: (i, 0))
+
+
+def _col_scale_spec(block_d: int, qblock: int) -> pl.BlockSpec:
+    """One upload row's scales as a (Dq/qblock, 1) column: a rank-1
+    (block_d/qblock,) block is refused unless it spans 128 scales."""
+    return pl.BlockSpec((block_d // qblock, 1), lambda i: (i, 0))
+
+
+def check_tiling(block_d: int, qblock: int) -> None:
+    """The compiled quantized kernels' tiling rule: a grid step reads
+    block_d/qblock scales per row on the sublane axis, so it must be a
+    whole multiple of 8 (interpret mode accepts any qblock multiple)."""
+    assert block_d % qblock == 0 and (block_d // qblock) % 8 == 0, (
+        f"block_d={block_d} must hold a multiple of 8 blocks of "
+        f"qblock={qblock} lanes")
+
+
+def _dequant_tile(q, s_t, qblock: int):
+    """(K, BD) int8 tile + (BD/qblock, K) transposed scales -> (K, BD)
+    f32 in VMEM."""
     K, BD = q.shape
     return (q.astype(jnp.float32).reshape(K, BD // qblock, qblock)
-            * s[:, :, None]).reshape(K, BD)
+            * s_t.T[:, :, None]).reshape(K, BD)
 
 
 def _agg_q8_kernel(w_ref, q_ref, s_ref, p_ref, o_ref, *, server_lr: float,
@@ -384,11 +428,11 @@ def _agg_q8_kernel(w_ref, q_ref, s_ref, p_ref, o_ref, *, server_lr: float,
     u = _dequant_tile(q_ref[...], s_ref[...], qblock)  # (K, BLOCK_D) f32
     p = p_ref[...].astype(jnp.float32)
     if mode == "mix":
-        g = jnp.einsum("k,kd->d", w, u)
+        g = _matvec(w, u)
         o_ref[...] = ((1.0 - jnp.sum(w)) * p + g).astype(o_ref.dtype)
         return
     wsum = jnp.maximum(jnp.sum(w), 1e-12)
-    g = jnp.einsum("k,kd->d", w, u) / wsum
+    g = _matvec(w, u) / wsum
     o_ref[...] = (p - server_lr * g).astype(o_ref.dtype)
 
 
@@ -397,14 +441,15 @@ def _avg_q8_kernel(w_ref, q_ref, s_ref, o_ref, *, server_lr: float,
     del server_lr
     w = _weights(w_ref[...], alpha, discount)
     u = _dequant_tile(q_ref[...], s_ref[...], qblock)
-    g = jnp.einsum("k,kd->d", w, u)
+    g = _matvec(w, u)
     if mode != "sum":  # "avg" normalizes; "sum" is the per-shard partial
         g = g / jnp.maximum(jnp.sum(w), 1e-12)
     o_ref[...] = g.astype(o_ref.dtype)
 
 
 def _pad_q8(q, scales, block_d: int, qblock: int):
-    """Pad the quantized buffer from Dq to a block_d multiple.  Padding
+    """Pad the quantized buffer from Dq to a block_d multiple and
+    transpose the scales to the kernels' (Dp/qblock, K) layout.  Padding
     blocks get scale 0 so they dequantize to exact zeros."""
     K, Dq = q.shape
     assert block_d % qblock == 0, (block_d, qblock)
@@ -414,7 +459,7 @@ def _pad_q8(q, scales, block_d: int, qblock: int):
     if pad:
         q = jnp.pad(q, ((0, 0), (0, pad)))
         scales = jnp.pad(scales, ((0, 0), (0, pad // qblock)))
-    return q, scales, Dq + pad
+    return q, scales.T, Dq + pad
 
 
 def safl_aggregate_q8(q: jax.Array, scales: jax.Array, weights: jax.Array,
@@ -433,7 +478,7 @@ def safl_aggregate_q8(q: jax.Array, scales: jax.Array, weights: jax.Array,
     K, Dq = q.shape
     q, scales, Dp = _pad_q8(q, scales, block_d, qblock)
     grid = (Dp // block_d,)
-    s_spec = pl.BlockSpec((K, block_d // qblock), lambda i: (0, i))
+    s_spec = _scale_spec(block_d, qblock, K)
     if mode in ("fedsgd", "mix"):
         assert params is not None
         D = params.shape[0]
@@ -474,7 +519,7 @@ def _sdga_q8_kernel(tau_ref, q_ref, s_ref, p_ref, m_ref, e_ref,
     w = _weights(tau_ref[...], alpha, discount)
     u = _dequant_tile(q_ref[...], s_ref[...], qblock)
     wsum = jnp.maximum(jnp.sum(w), 1e-12)
-    g = jnp.einsum("k,kd->d", w, u) / wsum
+    g = _matvec(w, u) / wsum
     m_new = momentum * m_ref[...].astype(jnp.float32) + g
     p = p_ref[...].astype(jnp.float32)
     e = e_ref[...].astype(jnp.float32)
@@ -517,7 +562,7 @@ def sdga_aggregate_q8(q: jax.Array, scales: jax.Array, staleness: jax.Array,
         in_specs=[
             pl.BlockSpec((K,), lambda i: (0,)),
             pl.BlockSpec((K, block_d), lambda i: (0, i)),
-            pl.BlockSpec((K, block_d // qblock), lambda i: (0, i)),
+            _scale_spec(block_d, qblock, K),
             vec_spec, vec_spec, vec_spec,
         ],
         out_specs=[vec_spec, vec_spec, vec_spec],
@@ -536,26 +581,60 @@ def sdga_aggregate_q8(q: jax.Array, scales: jax.Array, staleness: jax.Array,
 # ---------------------------------------------------------------------------
 
 
-def _unpack_q4_tile(qp, s, qblock: int):
-    """(K, BD/2) packed int8 tile + (K, BD/qblock) scales -> (K, BD) f32.
+def _q4_nibbles(qp):
+    """(K, BD/2) packed int8 tile -> its (low, high) nibbles as i32.
 
-    Two nibbles per byte (lane 2j low, lane 2j+1 high), sign-extended
-    from the symmetric [-7, 7] grid, then blockwise-dequantized — all in
-    VMEM, so the HBM read of the K x D tile is half the q8 bytes."""
-    K, half = qp.shape
-    u = qp.astype(jnp.uint8)
-    lo = (u & 0xF).astype(jnp.int32)
-    hi = (u >> 4).astype(jnp.int32)
-    lo = jnp.where(lo > 7, lo - 16, lo)
-    hi = jnp.where(hi > 7, hi - 16, hi)
-    q = jnp.stack([lo, hi], axis=-1).reshape(K, 2 * half)
-    return (q.astype(jnp.float32).reshape(K, (2 * half) // qblock, qblock)
-            * s[:, :, None]).reshape(K, 2 * half)
+    Byte j holds lane 2j in its low nibble and lane 2j+1 in its high one,
+    sign-extended from the symmetric [-7, 7] grid — all in VMEM, so the
+    HBM read of the K x D tile is half the q8 bytes."""
+    u = qp.astype(jnp.int32)  # nibble math in i32: Mosaic has no i8 shift
+    lo = u & 0xF
+    hi = (u >> 4) & 0xF
+    return (jnp.where(lo > 7, lo - 16, lo), jnp.where(hi > 7, hi - 16, hi))
+
+
+def _q4_halves(qp, s_t, qblock: int):
+    """(K, BD/2) packed int8 tile + (BD/qblock, K) transposed scales ->
+    the dequantized (low, high) nibble lanes, two (K, BD/2) f32 tiles.
+    A qblock of lanes is qblock/2 bytes, so both halves dequantize with
+    the same scales.  Callers reduce over K first and interleave only the
+    result: Mosaic's compile time for a lane interleave grows with its
+    rows (minutes at K = 64, block_d = 16384)."""
+    return tuple(_dequant_tile(h, s_t, qblock // 2) for h in _q4_nibbles(qp))
+
+
+def _interleave(lo, hi):
+    """(..., n) even and odd lanes -> (..., 2n)."""
+    return jnp.stack([lo, hi], axis=-1).reshape(*lo.shape[:-1],
+                                                2 * lo.shape[-1])
+
+
+def _matvec_q4(w, qp, s_t, qblock: int):
+    """(K,) weights x packed (K, BD/2) q4 tile -> (1, BD) weighted row
+    sum, each nibble half reduced over K before the one interleave of the
+    result.  Mosaic lowers a lane interleave only on 2-D rows, and cannot
+    reshape the (1, BD) row to (BD,): the q4 kernels' vectors travel as
+    (1, D) rows."""
+    red = lambda u: jnp.sum(w[:, None] * u, axis=0, keepdims=True)
+    return _interleave(*(red(h) for h in _q4_halves(qp, s_t, qblock)))
+
+
+def _q4_params(block_d: int, interpret: bool) -> dict:
+    """Scoped VMEM for the q4 kernels.  The lane interleave's (..., 2)
+    intermediate pads to 128 lanes, about 2 KiB of VMEM per tile lane:
+    past the default 16 MiB once FlatServer widens the tile for a coarse
+    qblock (31 MiB at block_d = 16384).  Twice that, never below the
+    default."""
+    if interpret:
+        return {}
+    return dict(compiler_params=pltpu.CompilerParams(
+        vmem_limit_bytes=max(16 << 20, 4096 * block_d)))
 
 
 def _pad_q4(qp, scales, block_d: int, qblock: int):
-    """Pad the packed buffer from Dq/2 to a block_d/2 multiple.  Padding
-    blocks get scale 0 so they dequantize to exact zeros."""
+    """Pad the packed buffer from Dq/2 to a block_d/2 multiple and
+    transpose the scales as :func:`_pad_q8` does.  Padding blocks get
+    scale 0 so they dequantize to exact zeros."""
     K, half = qp.shape
     Dq = 2 * half
     assert block_d % qblock == 0 and block_d % 2 == 0, (block_d, qblock)
@@ -565,7 +644,7 @@ def _pad_q4(qp, scales, block_d: int, qblock: int):
     if pad:
         qp = jnp.pad(qp, ((0, 0), (0, pad // 2)))
         scales = jnp.pad(scales, ((0, 0), (0, pad // qblock)))
-    return qp, scales, Dq + pad
+    return qp, scales.T, Dq + pad
 
 
 def _agg_q4_kernel(w_ref, qp_ref, s_ref, p_ref, o_ref, *, server_lr: float,
@@ -574,14 +653,13 @@ def _agg_q4_kernel(w_ref, qp_ref, s_ref, p_ref, o_ref, *, server_lr: float,
     unpack + blockwise dequantize in VMEM, then the same weighted
     reduction / server step (or fedasync mix) as the f32 kernel."""
     w = _weights(w_ref[...], alpha, discount)  # (K,)
-    u = _unpack_q4_tile(qp_ref[...], s_ref[...], qblock)  # (K, BLOCK_D)
+    g = _matvec_q4(w, qp_ref[...], s_ref[...], qblock)  # (1, BLOCK_D)
     p = p_ref[...].astype(jnp.float32)
     if mode == "mix":
-        g = jnp.einsum("k,kd->d", w, u)
         o_ref[...] = ((1.0 - jnp.sum(w)) * p + g).astype(o_ref.dtype)
         return
     wsum = jnp.maximum(jnp.sum(w), 1e-12)
-    g = jnp.einsum("k,kd->d", w, u) / wsum
+    g = g / wsum
     o_ref[...] = (p - server_lr * g).astype(o_ref.dtype)
 
 
@@ -589,8 +667,7 @@ def _avg_q4_kernel(w_ref, qp_ref, s_ref, o_ref, *, server_lr: float,
                    mode: str, alpha: float, discount: str, qblock: int):
     del server_lr
     w = _weights(w_ref[...], alpha, discount)
-    u = _unpack_q4_tile(qp_ref[...], s_ref[...], qblock)
-    g = jnp.einsum("k,kd->d", w, u)
+    g = _matvec_q4(w, qp_ref[...], s_ref[...], qblock)
     if mode != "sum":  # "avg" normalizes; "sum" is the per-shard partial
         g = g / jnp.maximum(jnp.sum(w), 1e-12)
     o_ref[...] = g.astype(o_ref.dtype)
@@ -614,18 +691,19 @@ def safl_aggregate_q4(qp: jax.Array, scales: jax.Array, weights: jax.Array,
     Dq = 2 * half
     qp, scales, Dp = _pad_q4(qp, scales, block_d, qblock)
     grid = (Dp // block_d,)
-    s_spec = pl.BlockSpec((K, block_d // qblock), lambda i: (0, i))
+    s_spec = _scale_spec(block_d, qblock, K)
+    row_spec = pl.BlockSpec((1, block_d), lambda i: (0, i))
     if mode in ("fedsgd", "mix"):
         assert params is not None
         D = params.shape[0]
         assert D <= Dq, (D, Dq)
         p = jnp.pad(params, (0, Dp - D)) if D < Dp else params
-        args = (weights, qp, scales, p)
+        args = (weights, qp, scales, p.reshape(1, Dp))
         in_specs = [
             pl.BlockSpec((K,), lambda i: (0,)),
             pl.BlockSpec((K, block_d // 2), lambda i: (0, i)),
             s_spec,
-            pl.BlockSpec((block_d,), lambda i: (i,)),
+            row_spec,
         ]
         kern, out_dtype, out_len = _agg_q4_kernel, params.dtype, D
     else:
@@ -641,17 +719,20 @@ def safl_aggregate_q4(qp: jax.Array, scales: jax.Array, weights: jax.Array,
                           discount=discount, qblock=qblock),
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((block_d,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((Dp,), out_dtype),
+        out_specs=row_spec,
+        out_shape=jax.ShapeDtypeStruct((1, Dp), out_dtype),
         interpret=interpret,
+        **_q4_params(block_d, interpret),
     )(*args)
-    return out[:out_len]
+    return out[0, :out_len]
 
 
 def _fold_q4_kernel(s_ref, a_ref, qp_ref, sc_ref, o_ref, *, qblock: int):
     """Streaming fold of one packed-q4 row tile: unpack + blockwise
-    dequantize the (BLOCK_D/2,) byte slice in VMEM, then o = beta*a + w*u."""
-    u = _unpack_q4_tile(qp_ref[...][None], sc_ref[...][None], qblock)[0]
+    dequantize the (1, BLOCK_D/2) byte slice in VMEM, then
+    o = beta*a + w*u (2-D rows, as in :func:`_fold_q8_kernel`)."""
+    u = _dequant_tile(_interleave(*_q4_nibbles(qp_ref[...])), sc_ref[...],
+                      qblock)
     o_ref[...] = s_ref[0] * a_ref[...].astype(jnp.float32) + s_ref[1] * u
 
 
@@ -671,21 +752,22 @@ def safl_fold_q4(acc: jax.Array, qp_row: jax.Array, scales_row: jax.Array,
     Dp = Dq + pad
     sw = jnp.stack([jnp.asarray(beta, jnp.float32),
                     jnp.asarray(w, jnp.float32)])
-    vec_spec = pl.BlockSpec((block_d,), lambda i: (i,))
+    row_spec = pl.BlockSpec((1, block_d), lambda i: (0, i))
     out = pl.pallas_call(
         functools.partial(_fold_q4_kernel, qblock=qblock),
         grid=(Dp // block_d,),
         in_specs=[
             pl.BlockSpec((2,), lambda i: (0,)),
-            vec_spec,
-            pl.BlockSpec((block_d // 2,), lambda i: (i,)),
-            pl.BlockSpec((block_d // qblock,), lambda i: (i,)),
+            row_spec,
+            pl.BlockSpec((1, block_d // 2), lambda i: (0, i)),
+            _col_scale_spec(block_d, qblock),
         ],
-        out_specs=vec_spec,
-        out_shape=jax.ShapeDtypeStruct((Dp,), jnp.float32),
+        out_specs=row_spec,
+        out_shape=jax.ShapeDtypeStruct((1, Dp), jnp.float32),
         interpret=interpret,
-    )(sw, acc, qp_row, scales_row)
-    return out[:Dq]
+        **_q4_params(block_d, interpret),
+    )(sw, acc[None], qp_row[None], scales_row[:, None])
+    return out[0, :Dq]
 
 
 def _sdga_q4_kernel(tau_ref, qp_ref, s_ref, p_ref, m_ref, e_ref,
@@ -693,9 +775,8 @@ def _sdga_q4_kernel(tau_ref, qp_ref, s_ref, p_ref, m_ref, e_ref,
                     alpha: float, momentum: float, ema_anchor: float,
                     ema_decay: float, qblock: int, discount: str):
     w = _weights(tau_ref[...], alpha, discount)
-    u = _unpack_q4_tile(qp_ref[...], s_ref[...], qblock)
     wsum = jnp.maximum(jnp.sum(w), 1e-12)
-    g = jnp.einsum("k,kd->d", w, u) / wsum
+    g = _matvec_q4(w, qp_ref[...], s_ref[...], qblock) / wsum
     m_new = momentum * m_ref[...].astype(jnp.float32) + g
     p = p_ref[...].astype(jnp.float32)
     e = e_ref[...].astype(jnp.float32)
@@ -724,11 +805,10 @@ def sdga_aggregate_q4(qp: jax.Array, scales: jax.Array, staleness: jax.Array,
     assert D <= Dq, (D, Dq)
     qp, scales, Dp = _pad_q4(qp, scales, block_d, qblock)
     pad = Dp - D
-    if pad:
-        params = jnp.pad(params, (0, pad))
-        mom = jnp.pad(mom, (0, pad))
-        ema = jnp.pad(ema, (0, pad))
-    vec_spec = pl.BlockSpec((block_d,), lambda i: (i,))
+    # (1, Dp) rows, as the q4 matvec yields (see :func:`_matvec_q4`)
+    params, mom, ema = (jnp.pad(v, (0, pad)).reshape(1, Dp)
+                        for v in (params, mom, ema))
+    vec_spec = pl.BlockSpec((1, block_d), lambda i: (0, i))
     kern = functools.partial(
         _sdga_q4_kernel, server_lr=server_lr, alpha=alpha, momentum=momentum,
         ema_anchor=ema_anchor, ema_decay=ema_decay, qblock=qblock,
@@ -739,129 +819,19 @@ def sdga_aggregate_q4(qp: jax.Array, scales: jax.Array, staleness: jax.Array,
         in_specs=[
             pl.BlockSpec((K,), lambda i: (0,)),
             pl.BlockSpec((K, block_d // 2), lambda i: (0, i)),
-            pl.BlockSpec((K, block_d // qblock), lambda i: (0, i)),
+            _scale_spec(block_d, qblock, K),
             vec_spec, vec_spec, vec_spec,
         ],
         out_specs=[vec_spec, vec_spec, vec_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((Dp,), params.dtype),
-            jax.ShapeDtypeStruct((Dp,), jnp.float32),
-            jax.ShapeDtypeStruct((Dp,), jnp.float32),
+            jax.ShapeDtypeStruct((1, Dp), params.dtype),
+            jax.ShapeDtypeStruct((1, Dp), jnp.float32),
+            jax.ShapeDtypeStruct((1, Dp), jnp.float32),
         ],
         interpret=interpret,
+        **_q4_params(block_d, interpret),
     )(staleness, qp, scales, params, mom, ema)
-    return tuple(o[:D] for o in outs)
-
-
-# ---------------------------------------------------------------------------
-# top-k sparse channel: fused gather-dequant-scatter-accumulate
-# ---------------------------------------------------------------------------
-
-
-def _topk_sum_kernel(w_ref, idx_ref, qv_ref, s_ref, o_ref, *, qblock: int,
-                     block_d: int):
-    """One (BLOCK_D,) output tile of sum_k w_k scatter(dequant(qv_k),
-    idx_k): the full compacted (K, nk) payload sits in VMEM each step;
-    coordinates are rebased to the tile and out-of-tile (and padding,
-    idx == d) lanes are clamped with zero contribution — no dense per-row
-    materialization, no data-dependent control flow."""
-    i = pl.program_id(0)
-    w = w_ref[...].astype(jnp.float32)  # (K,)
-    vals = _dequant_tile(qv_ref[...], s_ref[...], qblock)  # (K, nk) f32
-    c = (w[:, None] * vals).reshape(-1)
-    loc = idx_ref[...].reshape(-1) - i * block_d
-    inb = (loc >= 0) & (loc < block_d)
-    safe = jnp.where(inb, loc, 0)
-    o_ref[...] = jnp.zeros((block_d,), jnp.float32).at[safe].add(
-        jnp.where(inb, c, 0.0))
-
-
-def safl_aggregate_topk(idx: jax.Array, qv: jax.Array, scales: jax.Array,
-                        weights: jax.Array, d: int,
-                        qblock: int = QBLOCK, block_d: int = BLOCK_D,
-                        interpret: bool = True) -> jax.Array:
-    """Fused gather-dequant-scatter-accumulate over the sparse channel.
-
-    idx (K, nk) int32 dense coordinates (padding lanes carry idx == d),
-    qv (K, nk) int8 compacted values, scales (K, nk/qblock) f32,
-    weights (K,) FINAL reduction weights -> the unnormalized weighted
-    sum (d,) f32.  The dense row of an upload is never materialized:
-    each grid step scatters every upload's in-tile coordinates straight
-    into its (BLOCK_D,) accumulator tile.  Oracle:
-    :func:`repro.kernels.ref.topk_weighted_sum_ref` (the caller applies
-    the per-mode server step from the reduced sums).
-    """
-    K, nk = idx.shape
-    assert qv.shape == (K, nk) and nk % qblock == 0, (qv.shape, nk, qblock)
-    dp = d + ((-d) % block_d)
-    out = pl.pallas_call(
-        functools.partial(_topk_sum_kernel, qblock=qblock, block_d=block_d),
-        grid=(dp // block_d,),
-        in_specs=[
-            pl.BlockSpec((K,), lambda i: (0,)),
-            pl.BlockSpec((K, nk), lambda i: (0, 0)),
-            pl.BlockSpec((K, nk), lambda i: (0, 0)),
-            pl.BlockSpec((K, nk // qblock), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_d,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((dp,), jnp.float32),
-        interpret=interpret,
-    )(weights, idx, qv, scales)
-    return out[:d]
-
-
-def _fold_topk_kernel(sw_ref, a_ref, idx_ref, qv_ref, s_ref, o_ref, *,
-                      qblock: int, block_d: int):
-    """One (BLOCK_D,) tile of the sparse streaming fold
-    o = beta*a + w * scatter(dequant(qv), idx), tile-rebased as in
-    :func:`_topk_sum_kernel`."""
-    i = pl.program_id(0)
-    nk = qv_ref.shape[0]
-    vals = (qv_ref[...].astype(jnp.float32).reshape(nk // qblock, qblock)
-            * s_ref[...][:, None]).reshape(nk)
-    loc = idx_ref[...] - i * block_d
-    inb = (loc >= 0) & (loc < block_d)
-    safe = jnp.where(inb, loc, 0)
-    upd = jnp.zeros((block_d,), jnp.float32).at[safe].add(
-        jnp.where(inb, sw_ref[1] * vals, 0.0))
-    o_ref[...] = sw_ref[0] * a_ref[...].astype(jnp.float32) + upd
-
-
-def safl_fold_topk(acc: jax.Array, idx: jax.Array, qv: jax.Array,
-                   scales: jax.Array, w, beta=1.0, qblock: int = QBLOCK,
-                   block_d: int = BLOCK_D, interpret: bool = True
-                   ) -> jax.Array:
-    """Sparse streaming fold: acc (d,) f32 running sum, idx (nk,) int32 +
-    qv (nk,) int8 + scales (nk/qblock,) f32 one arriving sparse upload ->
-    beta*acc + w*scatter(dequant(qv), idx), one fused pass (oracle
-    :func:`repro.kernels.ref.fold_topk_ref`).  Padding coordinates
-    (idx == d) fall past the live range — masked out or scattered into
-    the sliced-off pad zone — so they never touch the first d lanes."""
-    d = acc.shape[0]
-    nk = qv.shape[0]
-    assert idx.shape == (nk,) and nk % qblock == 0, (idx.shape, nk, qblock)
-    pad = (-d) % block_d
-    if pad:
-        acc = jnp.pad(acc, (0, pad))
-    dp = d + pad
-    sw = jnp.stack([jnp.asarray(beta, jnp.float32),
-                    jnp.asarray(w, jnp.float32)])
-    vec_spec = pl.BlockSpec((block_d,), lambda i: (i,))
-    out = pl.pallas_call(
-        functools.partial(_fold_topk_kernel, qblock=qblock, block_d=block_d),
-        grid=(dp // block_d,),
-        in_specs=[
-            pl.BlockSpec((2,), lambda i: (0,)),
-            vec_spec,
-            pl.BlockSpec((nk,), lambda i: (0,)),
-            pl.BlockSpec((nk,), lambda i: (0,)),
-            pl.BlockSpec((nk // qblock,), lambda i: (0,)),
-        ],
-        out_specs=vec_spec,
-        out_shape=jax.ShapeDtypeStruct((dp,), jnp.float32),
-        interpret=interpret,
-    )(sw, acc, idx, qv, scales)
-    return out[:d]
+    return tuple(o[0, :D] for o in outs)
 
 
 # ---------------------------------------------------------------------------
@@ -930,7 +900,7 @@ def screen_rows_q8(q: jax.Array, scales: jax.Array, qblock: int = QBLOCK,
         grid=(Dp // block_d,),
         in_specs=[
             pl.BlockSpec((K, block_d), lambda i: (0, i)),
-            pl.BlockSpec((K, block_d // qblock), lambda i: (0, i)),
+            _scale_spec(block_d, qblock, K),
         ],
         out_specs=pl.BlockSpec((K,), lambda i: (0,)),
         out_shape=jax.ShapeDtypeStruct((K,), jnp.float32),
@@ -945,8 +915,8 @@ def _screen_q4_kernel(qp_ref, s_ref, o_ref, *, qblock: int):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    u = _unpack_q4_tile(qp_ref[...], s_ref[...], qblock)
-    o_ref[...] += jnp.sum(u * u, axis=1)
+    lo, hi = _q4_halves(qp_ref[...], s_ref[...], qblock)
+    o_ref[...] += jnp.sum(lo * lo, axis=1) + jnp.sum(hi * hi, axis=1)
 
 
 def screen_rows_q4(qp: jax.Array, scales: jax.Array, qblock: int = QBLOCK,
@@ -962,7 +932,7 @@ def screen_rows_q4(qp: jax.Array, scales: jax.Array, qblock: int = QBLOCK,
         grid=(Dp // block_d,),
         in_specs=[
             pl.BlockSpec((K, block_d // 2), lambda i: (0, i)),
-            pl.BlockSpec((K, block_d // qblock), lambda i: (0, i)),
+            _scale_spec(block_d, qblock, K),
         ],
         out_specs=pl.BlockSpec((K,), lambda i: (0,)),
         out_shape=jax.ShapeDtypeStruct((K,), jnp.float32),

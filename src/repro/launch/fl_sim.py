@@ -16,6 +16,7 @@ import numpy as np
 from repro.configs.base import FLConfig
 from repro.core import FLEngine
 from repro.data import build_client_shards, make_dataset, train_test_split
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.lstm import build_lstm
 from repro.models.vision_cnn import build_paper_model
 from repro.obs import export as obs_export
@@ -217,6 +218,7 @@ def main() -> None:
                          "timing, viewable in Perfetto)")
     ap.add_argument("--json-out", default="")
     args = ap.parse_args()
+    enable_compile_cache()
     trace_level = args.trace_level or ("upload" if args.trace_dir
                                        else "off")
 

@@ -18,15 +18,26 @@ HBM_BW = 819e9  # B/s
 ICI_BW = 50e9  # B/s per link
 
 
+def make_auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis in Auto (GSPMD) mode.  The
+    sharding rules (repro.sharding.rules) place arrays through
+    ``NamedSharding`` and let the partitioner propagate the rest;
+    ``jax.make_mesh`` defaults to Explicit axes, under which an input's
+    sharding becomes part of its type and a sharded-index gather (the
+    embedding lookup) is refused."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_auto_mesh(shape, axes)
 
 
 def make_debug_mesh(n_devices: int = 1):
     """CPU-sized mesh for tests: (1, n) over ("data", "model")."""
-    return jax.make_mesh((1, n_devices), ("data", "model"))
+    return make_auto_mesh((1, n_devices), ("data", "model"))
 
 
 def make_pod_mesh(n_devices: int):

@@ -2,12 +2,11 @@
 and an optional ``jax.profiler`` trace toggle (PR 10 tentpole, part 3).
 
 ``CompileLog`` promotes the compile-count guards that were duplicated
-across test files (``fn._cache_size()`` probes with ``-1`` fallbacks,
-``FlatServer.compile_count`` property reads) into one reusable API:
-register named targets, read their compile counts, assert bounds.  A
-count of ``-1`` means "unknown" (the jax internal probe is unavailable
-in this jax version) and passes every assertion — the same forgiving
-contract the test-local guards used.
+across test files (``fn._cache_size()`` probes, ``FlatServer.
+compile_count`` property reads) into one reusable API: register named
+targets, read their compile counts, assert bounds.  A target without a
+count raises instead of reporting "unknown", so a recompile guard can
+never pass vacuously.
 
 The module-level transfer counter backs the engine's "one host
 transfer per run" invariant: ``DeviceMetricsRing.flush`` /
@@ -30,12 +29,9 @@ from typing import Any, Dict, Optional
 
 
 def cache_size(fn) -> int:
-    """Compiled-program count of a jitted function via the private
-    ``_cache_size`` probe; ``-1`` when the probe is unavailable."""
-    try:
-        return int(fn._cache_size())
-    except Exception:
-        return -1
+    """Compiled-program count of a jitted function (jax's private
+    ``_cache_size`` probe)."""
+    return int(fn._cache_size())
 
 
 class CompileLog:
@@ -58,39 +54,31 @@ class CompileLog:
     def count(self, name: str) -> int:
         target, attr = self._targets[name]
         if attr is not None:
-            try:
-                return int(getattr(target, attr))
-            except Exception:
-                return -1
+            return int(getattr(target, attr))
         if callable(getattr(target, "_cache_size", None)):
             return cache_size(target)
-        c = getattr(target, "compile_count", None)
-        if c is None:
-            return -1
-        try:
-            return int(c)
-        except Exception:
-            return -1
+        return int(target.compile_count)
 
     def counts(self) -> Dict[str, int]:
         return {name: self.count(name) for name in self._targets}
 
     def assert_at_most(self, name: str, bound: int) -> int:
         c = self.count(name)
-        assert c == -1 or 0 <= c <= bound, (
+        assert 0 <= c <= bound, (
             f"{name}: {c} compiled programs > bound {bound}")
         return c
 
     def assert_exactly(self, name: str, n: int) -> int:
         c = self.count(name)
-        assert c in (n, -1), f"{name}: {c} compiled programs != {n}"
+        assert c == n, f"{name}: {c} compiled programs != {n}"
         return c
 
 
 def engine_compile_log(eng) -> CompileLog:
     """CompileLog pre-wired for an ``FLEngine``: the server step program,
     the streaming fold program (when the streaming channel is on) and
-    the batched wave program (once a batched run has resolved it)."""
+    the batched client program as ``"wave"`` (once a batched run has
+    built it: the semi-async wave, or the sync round)."""
     log = CompileLog().track("server_step", eng._server)
     if getattr(eng, "_streaming", False):
         log.track("server_fold", eng._server, attr="fold_compile_count")
@@ -150,23 +138,17 @@ class TransferScope:
 
 @contextlib.contextmanager
 def jax_profile(trace_dir: str, enabled: bool = True):
-    """Wrap a region in a ``jax.profiler`` trace when enabled; a
-    silent no-op when disabled, when ``trace_dir`` is empty, or when
-    the profiler is unavailable in this environment."""
+    """Wrap a region in a ``jax.profiler`` trace when enabled (a no-op
+    when disabled or when ``trace_dir`` is empty).  A profiler that
+    fails to start or stop raises: a run asked for a trace must not
+    quietly come back without one."""
     if not (enabled and trace_dir):
         yield
         return
     import jax
 
-    try:
-        jax.profiler.start_trace(trace_dir)
-    except Exception:
-        yield
-        return
+    jax.profiler.start_trace(trace_dir)
     try:
         yield
     finally:
-        try:
-            jax.profiler.stop_trace()
-        except Exception:
-            pass
+        jax.profiler.stop_trace()

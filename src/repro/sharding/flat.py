@@ -45,12 +45,8 @@ from typing import Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # newer jax promoted shard_map out of experimental
-    from jax import shard_map
-except ImportError:  # pragma: no cover - version-dependent import path
-    from jax.experimental.shard_map import shard_map
 
 POD_AXIS = "pod"
 EDGE_AXIS = "edge"
@@ -215,7 +211,7 @@ def podwise_sums(mesh: Mesh, partial_fn: Callable,
 
     return shard_map(local, mesh=mesh,
                      in_specs=(buf_spec, P(_row_axes(mesh))),
-                     out_specs=(P(), P()), check_rep=False)
+                     out_specs=(P(), P()), check_vma=False)
 
 
 def podwise_bank_sums(mesh: Mesh) -> Callable:
@@ -234,6 +230,37 @@ def podwise_bank_sums(mesh: Mesh) -> Callable:
         lambda bank_local, w_local: (bank_local.reshape(-1),
                                      jnp.sum(w_local)),
         quantized=False)
+
+
+def rowwise_fold(mesh: Mesh, fold_fn: Callable) -> Callable:
+    """The streaming fold on a mesh: bank row ``ridx`` is folded on the
+    shard that owns it, and every other shard keeps its row.
+
+    ``fold_fn(bank, *payload, ridx, w, beta) -> bank`` is the one-device
+    fold program body.  Each shard holds one row of the (n_shards, n)
+    accumulator bank; the payload and scalars arrive replicated.  The
+    fold runs inside ``shard_map`` because Mosaic (Pallas TPU) kernels
+    cannot be partitioned automatically: the owner folds its local row
+    (local index 0) and the others skip the kernel."""
+    row_spec = P(_row_axes(mesh), None)
+    pods = mesh.shape[POD_AXIS]
+
+    def local(bank, *rest):
+        *payload, ridx, w, beta = rest
+        shard = jax.lax.axis_index(POD_AXIS)
+        if is_hier(mesh):
+            shard = jax.lax.axis_index(EDGE_AXIS) * pods + shard
+        return jax.lax.cond(
+            shard == ridx,
+            lambda b: fold_fn(b, *payload, jnp.int32(0), w, beta),
+            lambda b: b, bank)
+
+    def fold(bank, *rest):
+        return shard_map(local, mesh=mesh,
+                         in_specs=(row_spec,) + (P(),) * len(rest),
+                         out_specs=row_spec, check_vma=False)(bank, *rest)
+
+    return fold
 
 
 def shard_rows(x: jax.Array, mesh: Optional[Mesh]) -> jax.Array:

@@ -181,8 +181,7 @@ def test_one_server_compilation_across_rounds(setup, aggregation):
                    te.x[:100], te.y[:100])
     res = eng.run(4)
     assert res.metrics.summary()["rounds"] == 4
-    # -1 = count unavailable on this jax version (private jit API)
-    assert eng._server.compile_count in (1, -1)
+    assert eng._server.compile_count == 1
 
 
 def test_batched_sync_round_matches_sequential(setup):

@@ -131,10 +131,7 @@ def test_tree_reduce_bitwise_matches_xor_oracle(key):
     bitwise, not just within tolerance — on every edge, and the
     cross-edge psum adds the edge partials."""
     from repro.kernels.safl_agg import edge_partial_reduce
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     E, Pods, D = 2, 2, 257
@@ -146,7 +143,7 @@ def test_tree_reduce_bitwise_matches_xor_oracle(key):
 
     got = np.asarray(jax.jit(shard_map(
         local, mesh=mesh, in_specs=(P(("edge", "pod"), None),),
-        out_specs=P(), check_rep=False))(x))
+        out_specs=P(), check_vma=False))(x))
     rows = [x[i] for i in range(E * Pods)]
     edge_partials = [ref.xor_tree_sum_ref(rows[e * Pods:(e + 1) * Pods])
                      for e in range(E)]

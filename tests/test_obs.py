@@ -293,13 +293,15 @@ def test_compile_log_contract():
     class Attr:
         folds = 2
 
-    log = (CompileLog().track("srv", Srv()).track("unknown", object())
+    log = (CompileLog().track("srv", Srv())
            .track("fold", Attr(), attr="folds"))
-    assert log.counts() == {"srv": 3, "unknown": -1, "fold": 2}
+    assert log.counts() == {"srv": 3, "fold": 2}
     assert log.assert_exactly("srv", 3) == 3
     assert log.assert_at_most("fold", 2) == 2
-    # -1 means "probe unavailable": passes every assertion
-    assert log.assert_exactly("unknown", 99) == -1
+    # a target with no count raises: no guard passes vacuously
+    log.track("unknown", object())
+    with pytest.raises(AttributeError):
+        log.assert_exactly("unknown", 99)
     with pytest.raises(AssertionError):
         log.assert_exactly("srv", 2)
     with pytest.raises(AssertionError):
@@ -309,8 +311,9 @@ def test_compile_log_contract():
 def test_cache_size_probe():
     fn = jax.jit(lambda x: x + 1)
     fn(1.0)
-    assert cache_size(fn) in (1, -1)
-    assert cache_size(object()) == -1
+    assert cache_size(fn) == 1
+    with pytest.raises(AttributeError):
+        cache_size(object())
 
 
 def test_engine_compile_log_targets(traced_pair):

@@ -513,7 +513,8 @@ def test_topk_codec_keeps_largest_and_feeds_residual(key):
 @pytest.mark.parametrize("mode", ["fedsgd", "fedbuff", "fedopt", "sdga"])
 def test_topk_server_matches_dense_scatter_oracle(mode, key):
     """FlatServer on the sparse (idx, qv, scales) wire == the f32
-    FlatServer on the densified rows, both backends."""
+    FlatServer on the densified rows.  Every backend takes the same XLA
+    scatter for topk; both backend names must reach it."""
     K, D, QB, NK = 6, 5000, 64, 512
     ks = jax.random.split(key, 3)
     buf = jax.random.normal(ks[0], (K, D), jnp.float32) * 0.1
@@ -682,7 +683,7 @@ def test_quantized_engine_runs_learns_one_compile(setup, mode):
     s = res.metrics.summary()
     assert s["rounds"] == 4
     assert s["best_accuracy"] > 0.15
-    assert eng._server.compile_count in (1, -1)
+    assert eng._server.compile_count == 1
 
 
 def test_model_target_uploads_compress_too(setup):
@@ -773,4 +774,4 @@ def test_wire_byte_accounting_ratios(setup):
 
 def test_wire_q4_engine_one_compile(setup):
     _, eng = _run_wire(setup, "q4", True, "fedsgd", rounds=4)
-    assert eng._server.compile_count in (1, -1)
+    assert eng._server.compile_count == 1

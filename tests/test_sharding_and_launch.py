@@ -75,10 +75,11 @@ def test_mini_dryrun_subprocess():
         from repro.sharding import param_specs
         from repro.launch.steps import make_train_step
         from repro.launch.dryrun import collective_bytes
+        from repro.launch.mesh import make_auto_mesh
         import dataclasses
         cfg = dataclasses.replace(reduced_config(ARCHS["qwen3-1.7b"]),
                                   d_model=256, n_heads=4, n_kv_heads=2)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_auto_mesh((2, 4), ("data", "model"))
         model = build_model(cfg)
         params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))
         pspecs = param_specs(params, cfg, mesh)
