@@ -154,6 +154,11 @@ _GRAD_ENVELOPE = 0.002
 # aggregation targets that upload model weights (vs cumulative gradients)
 _MODEL_TARGETS = ("fedavg", "fedasync")
 
+# wall-clock stage span in the jax.profiler trace (host plane, the device
+# planes' clock); costs about a microsecond when no profiler runs.  The
+# span names and their stats: repro/obs/README.md, "Stage spans"
+_span = jax.profiler.TraceAnnotation
+
 
 @dataclasses.dataclass
 class FLResult:
@@ -895,30 +900,34 @@ class FLEngine:
 
     # ------------------------------------------------------------------
     def run(self, n_rounds: int, log_every: int = 0) -> FLResult:
-        wall0 = _walltime.perf_counter()
-        if self.cfg.mode == "sync":
-            self._run_sync(n_rounds, log_every)
-        elif self.cfg.batch_clients:
-            self._run_semi_async_batched(n_rounds, log_every)
-        else:
-            self._run_semi_async(n_rounds, log_every)
-        self.wall_run_s += _walltime.perf_counter() - wall0
-        if self.tracer is not None:
-            # flush events of a horizon left open at run end (they stay
-            # pending across incremental run() calls otherwise)
-            self.tracer.tail()
-        if self._global_stale:
-            # flat end-to-end: the ONE unravel of the whole run
-            self.global_params = self.codec.unravel(self._flat_params)
-            self._global_stale = False
-        stats = self.sched.stats()
-        stats["staleness_bins"] = self._dev_stale_hist.copy()
-        # fault/defense accounting (engine side; crashed_uploads comes
-        # from the scheduler's own stats above)
-        stats["screened_uploads"] = self.screened_uploads
-        stats["clipped_uploads"] = self.clipped_uploads
-        stats["corrupted_uploads"] = self.corrupted_uploads
-        stats["byzantine_uploads"] = self.byzantine_uploads
+        with _span("safl.run") as run_span:
+            t0, wall0 = self.t_global, _walltime.perf_counter()
+            if self.cfg.mode == "sync":
+                self._run_sync(n_rounds, log_every)
+            elif self.cfg.batch_clients:
+                self._run_semi_async_batched(n_rounds, log_every)
+            else:
+                self._run_semi_async(n_rounds, log_every)
+            self.wall_run_s += _walltime.perf_counter() - wall0
+            if self.tracer is not None:
+                # flush events of a horizon left open at run end (they
+                # stay pending across incremental run() calls otherwise)
+                self.tracer.tail()
+            if self._global_stale:
+                # flat end-to-end: the ONE unravel of the whole run
+                with _span("safl.unravel"):
+                    self.global_params = self.codec.unravel(
+                        self._flat_params)
+                self._global_stale = False
+            stats = self.sched.stats()
+            stats["staleness_bins"] = self._dev_stale_hist.copy()
+            # fault/defense accounting (engine side; crashed_uploads comes
+            # from the scheduler's own stats above)
+            stats["screened_uploads"] = self.screened_uploads
+            stats["clipped_uploads"] = self.clipped_uploads
+            stats["corrupted_uploads"] = self.corrupted_uploads
+            stats["byzantine_uploads"] = self.byzantine_uploads
+            run_span.set_metadata(rounds=self.t_global - t0)
         return FLResult(self.metrics, self.global_params,
                         self.staleness_hist, self.idle_time,
                         participation=self.sched.participation.copy(),
@@ -1133,7 +1142,10 @@ class FLEngine:
         run-end ring flush.  Waves are power-of-two bucketed
         (``wave_buckets``): padding lanes duplicate a real lane's inputs
         and scatter to the dropped slot K, so compilation is bounded at
-        O(log K) wave programs with unchanged numerics."""
+        O(log K) wave programs with unchanged numerics.
+
+        Each stage runs inside a ``safl.*`` profiler span (the table in
+        ``repro/obs/README.md``)."""
         cfg = self.cfg
         target = "params" if cfg.aggregation in _MODEL_TARGETS else "grad"
         if self.wave_impl_resolved is None:
@@ -1166,394 +1178,479 @@ class FLEngine:
         # channels: acc, loss, update_norm + the defense layer's
         # cumulative screened/clipped upload counts (f32 scalars — exact
         # for any realistic count)
-        ring = DeviceMetricsRing(n_rounds + 1, channels=5,
-                                 stale_bins=_STALE_BINS,
-                                 n_clients=len(self.clients))
+        with _span("safl.ring"):
+            ring = DeviceMetricsRing(n_rounds + 1, channels=5,
+                                     stale_bins=_STALE_BINS,
+                                     n_clients=len(self.clients))
         pending: List[Dict] = []  # host-side fields per recorded round
 
         tree_stack = jax.tree_util.tree_map
         self.sched.resume()
         while self.t_global < n_rounds:
             r = self.t_global
-            # ---- pop the scheduler to the aggregation horizon (K
-            # admitted uploads); the scheduler re-pushes successor events
-            # at pop time from schedule data only, so the heap evolves
-            # exactly as in the sequential path.  Policy-rejected uploads
-            # are handled inline: the client discards its local progress
-            # and adopts the round-r global model (selective training) —
-            # which is also what makes a later ADMITTED event of the same
-            # client this horizon train from the adopted weights. ----
-            events: List[Tuple[float, int]] = []
-            stal: List[int] = []
-            evfaults: List = []  # per admitted slot: FaultDraw or None
-            evcomp: List[float] = []  # per admitted slot: compute seconds
-            n_adm: Dict[int, int] = {}  # admitted events per cid so far
-            # discard-and-resync decisions (reject / crash) landing AFTER
-            # a client's admitted event of this horizon cannot reset the
-            # client inline — its earlier training still has to run.  The
-            # reset lands between its wave lanes instead: the client's
-            # next admitted lane restarts from the round-r global row
-            # (force_global), and a reset with no later admitted event
-            # leaves the client on the global model when the horizon
-            # closes (resync_after) — exactly where the sequential
-            # oracle's inline reset puts it.
-            force_global: set = set()  # (cid, wave) lanes
-            resync_after: set = set()  # cids reset after their last lane
-            # the horizon clock advances on EVERY popped event, admitted
-            # or not — under rate control the deadline of a timeout
-            # horizon is typically crossed by an idled upload, and the
-            # sequential oracle stamps _last_agg_time with that event's
-            # time, so the batched path must too (count horizons never
-            # fire on a non-admitted pop: the buffer didn't grow)
-            t_pop = 0.0
-            while not (events and self._horizon_due(len(events), t_pop)):
-                ev = self.sched.pop(r)
-                if ev is None:
-                    break
-                t_pop = ev.time
-                if not ev.admitted:
-                    if ev.verdict == "idle":
-                        # back-pressure: nothing changes for the client —
-                        # its wave chain (and version) stay intact, only
-                        # the horizon clock advanced
-                        continue
-                    # "reject" (selective training) and "crash" (fault
-                    # layer) both discard the client's local progress and
-                    # resync it to the round-r global model
-                    k_adm = n_adm.get(ev.cid, 0)
-                    if k_adm == 0:
-                        flats[ev.cid] = self._flat_params
-                        c = self.clients[ev.cid]
-                        c.model_state = self.global_state
-                        c.version = r
-                    else:
-                        force_global.add((ev.cid, k_adm))
-                        resync_after.add(ev.cid)
-                    continue
-                n_adm[ev.cid] = n_adm.get(ev.cid, 0) + 1
-                resync_after.discard(ev.cid)
-                stal.append(ev.staleness)
-                evfaults.append(ev.fault)
-                evcomp.append(ev.compute_s)
-                events.append((ev.time, ev.cid))
-            if not events:
-                break
-            now = t_pop
-            kh = len(events)  # this horizon's admitted upload count
-            sizes = [self.clients[cid].n_samples for _, cid in events]
-            wh = betah = None
-            pend: Dict[int, tuple] = {}
-            next_fold = 0
-            # defense factors per horizon slot (np.float32), filled as
-            # each wave is screened; consumed by the in-order streaming
-            # fold loop and the buffered server round alike
-            hfac: Optional[Dict[int, np.float32]] = (
-                {} if self._defense != "none" else None)
-            if self._streaming:
-                # discount-at-ingest weights for the whole horizon,
-                # slot-ordered (identical np kernels to the sequential
-                # path's per-upload singleton — bitwise the same folds)
-                wh = self._weight_vector(stal, sizes)
-                if cfg.aggregation == "fedasync":
-                    betah = np.float32(1.0) - wh
-
-            # ---- wave decomposition ----
-            waves: List[List[Tuple[int, int]]] = []  # per wave: (slot, cid)
-            n_events: Dict[int, int] = {}
-            for slot, (_, cid) in enumerate(events):
-                w = n_events.get(cid, 0)
-                n_events[cid] = w + 1
-                if w == len(waves):
-                    waves.append([])
-                waves[w].append((slot, cid))
-
-            g_flat, g_state = self._flat_params, self.global_state
-            nbytes = self._upload_nbytes()
-            prev_new_flat = prev_states = None
-            # refresh result per client with further events this horizon:
-            # None = adopted the round-r global model, int = row index into
-            # the previous wave's outputs (continue the local chain)
-            carry: Dict[int, Optional[int]] = {}
-            last_slot_state = None  # state of the event in slot K-1
-            state_parts: List[Pytree] = []  # fedavg state mean (order-free)
-            size_parts: List[int] = []
-            for w, members in enumerate(waves):
-                kw = len(members)
-                self.wave_size_hist[kw] = \
-                    self.wave_size_hist.get(kw, 0) + 1
-                kb = self._wave_bucket(kw)
-                npad = kb - kw
-                # bucketing: padding lanes duplicate the first member's
-                # inputs (lanes are independent, so real lanes are
-                # untouched); their rows scatter to the dropped slot K
-                # and host bookkeeping iterates real members only
-                cids = [cid for _, cid in members] \
-                    + [members[0][1]] * npad
-                if w == 0:
-                    starts = stack_rows([flats[cid] for cid in cids])
-                    states = tree_stack(
-                        lambda *xs: jnp.stack(xs),
-                        *[self.clients[cid].model_state for cid in cids])
-                else:
-                    # a force_global lane restarts from the round-r
-                    # global model (a reject/crash landed between this
-                    # client's admitted events) — same row/state source
-                    # as an adopting lane, so it reuses the None path
-                    rows = [None if (cid, w) in force_global
-                            else carry.get(cid) for cid in cids]
-                    if all(rv is None for rv in rows):
-                        # common case: every wave-0 member adopted the
-                        # round-r global model
-                        starts = jnp.broadcast_to(g_flat,
-                                                  (kb, self.codec.d))
-                        states = tree_stack(
-                            lambda l: jnp.broadcast_to(l, (kb,) + l.shape),
-                            g_state)
-                    elif all(rv is not None for rv in rows):
-                        ridx = jnp.asarray(rows)
-                        starts = prev_new_flat[ridx]
-                        states = tree_stack(lambda l: l[ridx], prev_states)
-                    else:  # mixed: force_global lanes next to continuing
-                        # local chains (mid-horizon crashes), or a future
-                        # schedule the refresh rule doesn't cover
-                        starts = stack_rows(
-                            [g_flat if rv is None else prev_new_flat[rv]
-                             for rv in rows])
-                        states = tree_stack(
-                            lambda *ls: jnp.stack(ls),
-                            *[g_state if rv is None else tree_stack(
-                                lambda l, rv=rv: l[rv], prev_states)
-                              for rv in rows])
-                vecs, new_flat, new_states, _losses = wave_fn(
-                    starts, states, xs_all, ys_all, mask_all,
-                    jnp.asarray(cids), cfg.client_lr)
-
-                # ---- serialize the wave into the server channel ----
-                # prows: the wave's stacked wire-payload arrays ((vecs,)
-                # on f32, (q, s) on q8/q4, (idx, qv, s) on topk)
-                new_res = None
-                if use_ef:
-                    # padding lanes read member 0's pre-update residual
-                    # (their outputs are discarded below)
-                    res = jnp.stack([self._residual(cid) for cid in cids])
-                if self._quant:
-                    if use_ef:
-                        q, s, new_res = self.codec.quantize_rows(vecs, res)
-                    else:
-                        q, s = self.codec.quantize_rows_nores(vecs)
-                    prows = (q, s)
-                elif self._q4:
-                    # per-lane (cid, counter) PRNG keys; real lanes
-                    # consume their client's next counter, padding lanes
-                    # repeat lane 0's key (rows dropped either way)
-                    ctrs = [self._next_counter(cid) for cid in cids[:kw]]
-                    ctrs += [ctrs[0]] * npad
-                    cids_v = jnp.asarray(cids, jnp.int32)
-                    ctrs_v = jnp.asarray(ctrs, jnp.int32)
-                    if use_ef:
-                        q, s, new_res = self.codec.quantize_rows_q4(
-                            vecs, res, cfg.seed, cids_v, ctrs_v)
-                    else:
-                        q, s = self.codec.quantize_rows_q4_nores(
-                            vecs, cfg.seed, cids_v, ctrs_v)
-                    prows = (q, s)
-                elif self._topk:
-                    if use_ef:
-                        ti, tq, ts, new_res = \
-                            self.codec.quantize_rows_topk(vecs, res)
-                    else:
-                        ti, tq, ts = \
-                            self.codec.quantize_rows_topk_nores(vecs)
-                    prows = (ti, tq, ts)
-                else:
-                    prows = (vecs,)
-                if new_res is not None:
-                    for row, cid in enumerate(cids[:kw]):
-                        self._residuals[cid] = new_res[row]
-                # wire-level faults land on the serialized rows (the
-                # residuals above were already updated against the clean
-                # payload — the client believes it sent a good row);
-                # padding lanes carry no fault, and the appliers leave
-                # unfaulted lanes bitwise untouched
-                wfaults = [evfaults[slot] for slot, _ in members] \
-                    + [None] * npad
-                prows = self._apply_payload_faults(prows, wfaults)
-                if hfac is not None:
-                    # defense screen: one fused per-row pass over the
-                    # wave (padding lanes screened but never counted);
-                    # verdicts are keyed by horizon slot so the
-                    # streaming fold below consumes them in arrival
-                    # order, exactly like the sequential path
-                    fac = self._screen_factors(prows, kw)
-                    for row, (slot, _cid) in enumerate(members):
-                        hfac[slot] = fac[row]
-                    if not self._streaming \
-                            and bool((fac == np.float32(0.0)).any()):
-                        mask = np.zeros(kb, bool)
-                        mask[:kw] = fac == np.float32(0.0)
-                        prows = self._zero_screened_rows(prows, mask)
-                if self._streaming:
-                    # hold-and-release: waves surface rows out of arrival
-                    # order (wave 0 spans the whole horizon), but the
-                    # sequential oracle folds in arrival order — so rows
-                    # park in ``pend`` and fold strictly in slot order,
-                    # which makes the batched fold chain the sequential
-                    # one by construction (and keeps fedasync's
-                    # non-commuting mix exact)
-                    for row, (slot, _cid) in enumerate(members):
-                        pend[slot] = tuple(a[row] for a in prows)
-                    while next_fold in pend:
-                        payload = pend.pop(next_fold)
-                        fw = wh[next_fold]
-                        if hfac is not None:
-                            fv = hfac[next_fold]
-                            if fv == np.float32(0.0):
-                                # screened: the fold is skipped outright
-                                # (0 x NaN is NaN) — skip() records the
-                                # arrival with an exact 0.0 weight
-                                self._accum.skip(
-                                    shard=self._fold_shard(next_fold),
-                                    staleness=stal[next_fold])
-                                next_fold += 1
+            # the server step below advances t_global to r + 1: the
+            # round index the SpanTracer records for this horizon
+            with _span("safl.round", round=r + 1) as round_span:
+                # ---- pop the scheduler to the aggregation horizon (K
+                # admitted uploads); the scheduler re-pushes successor
+                # events at pop time from schedule data only, so the heap
+                # evolves exactly as in the sequential path.
+                # Policy-rejected uploads are handled inline: the client
+                # discards its local progress and adopts the round-r
+                # global model (selective training) — which is also what
+                # makes a later ADMITTED event of the same client this
+                # horizon train from the adopted weights. ----
+                events: List[Tuple[float, int]] = []
+                stal: List[int] = []
+                evfaults: List = []  # per admitted slot: FaultDraw or None
+                evcomp: List[float] = []  # per admitted slot: compute s
+                n_adm: Dict[int, int] = {}  # admitted events per cid
+                # discard-and-resync decisions (reject / crash) landing
+                # AFTER a client's admitted event of this horizon cannot
+                # reset the client inline — its earlier training still
+                # has to run.  The reset lands between its wave lanes
+                # instead: the client's next admitted lane restarts from
+                # the round-r global row (force_global), and a reset with
+                # no later admitted event leaves the client on the global
+                # model when the horizon closes (resync_after) — exactly
+                # where the sequential oracle's inline reset puts it.
+                force_global: set = set()  # (cid, wave) lanes
+                resync_after: set = set()  # cids reset after last lane
+                # the horizon clock advances on EVERY popped event,
+                # admitted or not — under rate control the deadline of a
+                # timeout horizon is typically crossed by an idled
+                # upload, and the sequential oracle stamps _last_agg_time
+                # with that event's time, so the batched path must too
+                # (count horizons never fire on a non-admitted pop: the
+                # buffer didn't grow)
+                t_pop = 0.0
+                popped = 0
+                with _span("safl.pop") as pop_span:
+                    while not (events
+                               and self._horizon_due(len(events), t_pop)):
+                        ev = self.sched.pop(r)
+                        if ev is None:
+                            break
+                        popped += 1
+                        t_pop = ev.time
+                        if not ev.admitted:
+                            if ev.verdict == "idle":
+                                # back-pressure: nothing changes for the
+                                # client — its wave chain (and version)
+                                # stay intact, only the horizon clock
+                                # advanced
                                 continue
-                            fw = np.float32(fw * fv)
-                        self._accum.fold(
-                            payload, w=fw,
-                            beta=(np.float32(1.0) - fw
-                                  if betah is not None else 1.0),
-                            shard=self._fold_shard(next_fold),
-                            staleness=stal[next_fold])
-                        next_fold += 1
-                else:
-                    # padding lanes get the first out-of-range slot:
-                    # dropped by the scatter (write_rows mode="drop")
-                    slots = np.asarray(
-                        [slot for slot, _ in members]
-                        + [self._horizon_target] * npad, np.int32)
-                    if self._quant or self._q4:
-                        self._qbuf.write_rows(*prows, slots)
-                    elif self._topk:
-                        self._tbuf.write_rows(*prows, slots)
+                            # "reject" (selective training) and "crash"
+                            # (fault layer) both discard the client's
+                            # local progress and resync it to the
+                            # round-r global model
+                            k_adm = n_adm.get(ev.cid, 0)
+                            if k_adm == 0:
+                                flats[ev.cid] = self._flat_params
+                                c = self.clients[ev.cid]
+                                c.model_state = self.global_state
+                                c.version = r
+                            else:
+                                force_global.add((ev.cid, k_adm))
+                                resync_after.add(ev.cid)
+                            continue
+                        n_adm[ev.cid] = n_adm.get(ev.cid, 0) + 1
+                        resync_after.discard(ev.cid)
+                        stal.append(ev.staleness)
+                        evfaults.append(ev.fault)
+                        evcomp.append(ev.compute_s)
+                        events.append((ev.time, ev.cid))
+                    pop_span.set_metadata(popped=popped,
+                                          admitted=len(events))
+                if not events:
+                    break
+                now = t_pop
+                kh = len(events)  # this horizon's admitted upload count
+                sizes = [self.clients[cid].n_samples for _, cid in events]
+                wh = betah = None
+                pend: Dict[int, tuple] = {}
+                next_fold = 0
+                # defense factors per horizon slot (np.float32), filled
+                # as each wave is screened; consumed by the in-order
+                # streaming fold loop and the buffered server round alike
+                hfac: Optional[Dict[int, np.float32]] = (
+                    {} if self._defense != "none" else None)
+                if self._streaming:
+                    # discount-at-ingest weights for the whole horizon,
+                    # slot-ordered (identical np kernels to the sequential
+                    # path's per-upload singleton — bitwise the same folds)
+                    wh = self._weight_vector(stal, sizes)
+                    if cfg.aggregation == "fedasync":
+                        betah = np.float32(1.0) - wh
+
+                # ---- wave decomposition ----
+                waves: List[List[Tuple[int, int]]] = []  # (slot, cid)
+                n_events: Dict[int, int] = {}
+                for slot, (_, cid) in enumerate(events):
+                    w = n_events.get(cid, 0)
+                    n_events[cid] = w + 1
+                    if w == len(waves):
+                        waves.append([])
+                    waves[w].append((slot, cid))
+                round_span.set_metadata(uploads=kh, waves=len(waves))
+
+                g_flat, g_state = self._flat_params, self.global_state
+                nbytes = self._upload_nbytes()
+                prev_new_flat = prev_states = None
+                # refresh result per client with further events this
+                # horizon: None = adopted the round-r global model, int =
+                # row index into the previous wave's outputs (continue
+                # the local chain)
+                carry: Dict[int, Optional[int]] = {}
+                last_slot_state = None  # state of the event in slot K-1
+                state_parts: List[Pytree] = []  # fedavg state mean
+                size_parts: List[int] = []
+                for w, members in enumerate(waves):
+                    kw = len(members)
+                    self.wave_size_hist[kw] = \
+                        self.wave_size_hist.get(kw, 0) + 1
+                    kb = self._wave_bucket(kw)
+                    with _span("safl.wave", wave=w, lanes=kw, bucket=kb):
+                        npad = kb - kw
+                        # bucketing: padding lanes duplicate the first
+                        # member's inputs (lanes are independent, so real
+                        # lanes are untouched); their rows scatter to the
+                        # dropped slot K and host bookkeeping iterates
+                        # real members only
+                        cids = [cid for _, cid in members] \
+                            + [members[0][1]] * npad
+                        with _span("safl.gather") as sp:
+                            starts, states, stacked = self._gather_wave(
+                                w, cids, force_global, carry, g_flat,
+                                g_state, prev_new_flat, prev_states)
+                            sp.set_metadata(stacked=stacked)
+                        with _span("safl.train"):
+                            vecs, new_flat, new_states, _losses = wave_fn(
+                                starts, states, xs_all, ys_all, mask_all,
+                                jnp.asarray(cids), cfg.client_lr)
+                        with _span("safl.encode"):
+                            prows = self._encode_wave(
+                                vecs, members, cids, evfaults, hfac, use_ef)
+                        with _span("safl.fold") as sp:
+                            next_fold, folds, skipped = self._fold_wave(
+                                prows, members, npad, pend, next_fold,
+                                wh, betah, hfac, stal)
+                            sp.set_metadata(folds=folds, skipped=skipped)
+
+                        # ---- host bookkeeping + client refresh ----
+                        with _span("safl.refresh") as sp:
+                            sliced = 0
+                            # model targets on the quantized channel: the
+                            # server-side state view is the int8
+                            # roundtrip (identity otherwise)
+                            up_states = (self._state_q8_rows(new_states)
+                                         if target == "params"
+                                         else new_states)
+                            state_parts.append(
+                                up_states if not npad
+                                else tree_stack(lambda l: l[:kw],
+                                                up_states))
+                            for row, (slot, cid) in enumerate(members):
+                                c = self.clients[cid]
+                                self.tx_bytes += nbytes
+                                # staleness was recorded at pop time from
+                                # the scheduler's projected versions
+                                # (== r - c.version here: the projection
+                                # mirrors this refresh rule)
+                                size_parts.append(c.n_samples)
+                                if (slot == kh - 1
+                                        and cfg.aggregation != "fedavg"):
+                                    # fedavg takes the weighted state
+                                    # mean instead
+                                    last_slot_state = tree_stack(
+                                        lambda l, row=row: l[row],
+                                        up_states)
+                                    sliced += 1
+                                # refresh rule (paper §2.2.2): adopt the
+                                # round-r global model iff one arrived
+                                # since this client's version; else
+                                # continue the local chain from w_end
+                                adopt = c.version < r
+                                c.version = r
+                                if n_events[cid] > w + 1:  # more events
+                                    carry[cid] = None if adopt else row
+                                elif adopt:
+                                    flats[cid] = g_flat
+                                    c.model_state = g_state
+                                else:
+                                    flats[cid] = new_flat[row]
+                                    c.model_state = tree_stack(
+                                        lambda l, row=row: l[row],
+                                        new_states)
+                                    sliced += 1
+                            prev_new_flat, prev_states = new_flat, new_states
+                            if w == len(waves) - 1:
+                                # reject/crash resets that landed after a
+                                # client's last admitted lane: the client
+                                # ends the horizon on the round-r global
+                                # model, like the sequential oracle's
+                                # inline reset
+                                for cid in resync_after:
+                                    flats[cid] = g_flat
+                                    c = self.clients[cid]
+                                    c.model_state = g_state
+                                    c.version = r
+                            sp.set_metadata(sliced=sliced)
+
+                # ---- fused server round (no host sync) ----
+                facs = ([hfac[i] for i in range(kh)]
+                        if hfac is not None else None)
+                with _span("safl.finalize"):
+                    if self._streaming:
+                        assert next_fold == kh, (next_fold, kh)
+                        m = self._server_round_streaming(stal)
                     else:
-                        self._buf = flatbuf.write_rows(
-                            self._buf, prows[0], jnp.asarray(slots))
-
-                # ---- host bookkeeping + client refresh ----
-                # model targets on the quantized channel: the server-side
-                # state view is the int8 roundtrip (identity otherwise)
-                up_states = (self._state_q8_rows(new_states)
-                             if target == "params" else new_states)
-                state_parts.append(
-                    up_states if not npad
-                    else tree_stack(lambda l: l[:kw], up_states))
-                for row, (slot, cid) in enumerate(members):
-                    c = self.clients[cid]
-                    self.tx_bytes += nbytes
-                    # staleness was recorded at pop time from the
-                    # scheduler's projected versions (== r - c.version
-                    # here: the projection mirrors this refresh rule)
-                    size_parts.append(c.n_samples)
-                    if slot == kh - 1 and cfg.aggregation != "fedavg":
-                        # fedavg takes the weighted state mean instead
-                        last_slot_state = jax.tree_util.tree_map(
-                            lambda l, row=row: l[row], up_states)
-                    # refresh rule (paper §2.2.2): adopt the round-r
-                    # global model iff one arrived since this client's
-                    # version; else continue the local chain from w_end
-                    adopt = c.version < r
-                    c.version = r
-                    if n_events[cid] > w + 1:  # more events this horizon
-                        carry[cid] = None if adopt else row
-                    elif adopt:
-                        flats[cid] = g_flat
-                        c.model_state = g_state
+                        m = self._server_round(stal, sizes, facs)
+                t_open = self._last_agg_time
+                self._last_agg_time = now
+                self._global_stale = True
+                if self.tracer is not None:
+                    # per-slot values are identical to the sequential
+                    # oracle's (same pop sequence, same host math); the
+                    # tracer's sorted flush makes emission order
+                    # irrelevant
+                    for slot, (t_ev, cid) in enumerate(events):
+                        self.tracer.upload(
+                            slot=slot, cid=cid, t=t_ev,
+                            compute_s=evcomp[slot],
+                            comm_s=self.clients[cid].comm_time,
+                            staleness=stal[slot], nbytes=nbytes,
+                            wire=self._wire,
+                            fac=None if hfac is None else hfac[slot])
+                    self._trace_round(stal, sizes, facs, t_open, now)
+                # device-resident sched stats: scatter-add this round's
+                # staleness values + client ids (host ints in — the ring
+                # pads them to a power of two so queue/timeout horizons
+                # keep the writer at O(log K) compiles; donated in-place
+                # writes, host transfer happens once, at the run-end
+                # flush)
+                ring.append_sched(stal, [cid for _, cid in events])
+                with _span("safl.state"):
+                    if cfg.aggregation == "fedavg":
+                        stacked = (state_parts[0] if len(state_parts) == 1
+                                   else tree_stack(
+                                       lambda *xs: jnp.concatenate(xs),
+                                       *state_parts))
+                        if jax.tree_util.tree_leaves(stacked):
+                            self.global_state = agg.weighted_mean(
+                                stacked,
+                                jnp.asarray(size_parts, jnp.float32))
                     else:
-                        flats[cid] = new_flat[row]
-                        c.model_state = jax.tree_util.tree_map(
-                            lambda l, row=row: l[row], new_states)
-                prev_new_flat, prev_states = new_flat, new_states
+                        self.global_state = last_slot_state
 
-            # reject/crash resets that landed after a client's last
-            # admitted lane: the client ends the horizon on the round-r
-            # global model, like the sequential oracle's inline reset
-            for cid in resync_after:
-                flats[cid] = g_flat
-                c = self.clients[cid]
-                c.model_state = g_state
-                c.version = r
-
-            # ---- fused server round (no host sync) ----
-            facs = ([hfac[i] for i in range(kh)]
-                    if hfac is not None else None)
-            if self._streaming:
-                assert next_fold == kh, (next_fold, kh)
-                m = self._server_round_streaming(stal)
-            else:
-                m = self._server_round(stal, sizes, facs)
-            t_open = self._last_agg_time
-            self._last_agg_time = now
-            self._global_stale = True
-            if self.tracer is not None:
-                # per-slot values are identical to the sequential
-                # oracle's (same pop sequence, same host math); the
-                # tracer's sorted flush makes emission order irrelevant
-                for slot, (t_ev, cid) in enumerate(events):
-                    self.tracer.upload(
-                        slot=slot, cid=cid, t=t_ev,
-                        compute_s=evcomp[slot],
-                        comm_s=self.clients[cid].comm_time,
-                        staleness=stal[slot], nbytes=nbytes,
-                        wire=self._wire,
-                        fac=None if hfac is None else hfac[slot])
-                self._trace_round(stal, sizes, facs, t_open, now)
-            # device-resident sched stats: scatter-add this round's
-            # staleness values + client ids (host ints in — the ring pads
-            # them to a power of two so queue/timeout horizons keep the
-            # writer at O(log K) compiles; donated in-place writes, host
-            # transfer happens once, at the run-end flush)
-            ring.append_sched(stal, [cid for _, cid in events])
-            if cfg.aggregation == "fedavg":
-                stacked = (state_parts[0] if len(state_parts) == 1
-                           else tree_stack(
-                               lambda *xs: jnp.concatenate(xs),
-                               *state_parts))
-                if jax.tree_util.tree_leaves(stacked):
-                    self.global_state = agg.weighted_mean(
-                        stacked, jnp.asarray(size_parts, jnp.float32))
-            else:
-                self.global_state = last_slot_state
-
-            # ---- eval_every-gated eval into the device metrics ring ----
-            rnd = self.t_global
-            if self._eval_due(rnd, n_rounds):
-                acc, loss = eval_fn(self._flat_params, self.global_state,
-                                    self.test_x, self.test_y)
-                ring.append(acc, loss, m["update_norm"],
-                            np.float32(self.screened_uploads),
-                            np.float32(self.clipped_uploads))
-                pending.append(dict(
-                    round=rnd, sim_time=now + self._agg_overhead(),
-                    tx_bytes=self.tx_bytes, rx_bytes=self.rx_bytes,
-                    mean_staleness=float(np.mean(stal)),
-                    max_staleness=int(max(stal))))
-                if log_every and rnd % log_every == 0:
-                    # opt-in logging is the one place a fetch is allowed
-                    print(f"  [SAFL-{cfg.aggregation}] round {rnd} "
-                          f"acc={float(acc):.4f} loss={float(loss):.4f} "
-                          f"stale={np.mean(stal):.2f}")
+                # ---- eval_every-gated eval into the device metrics
+                # ring ----
+                with _span("safl.eval"):
+                    rnd = self.t_global
+                    if self._eval_due(rnd, n_rounds):
+                        acc, loss = eval_fn(self._flat_params,
+                                            self.global_state,
+                                            self.test_x, self.test_y)
+                        ring.append(acc, loss, m["update_norm"],
+                                    np.float32(self.screened_uploads),
+                                    np.float32(self.clipped_uploads))
+                        pending.append(dict(
+                            round=rnd, sim_time=now + self._agg_overhead(),
+                            tx_bytes=self.tx_bytes, rx_bytes=self.rx_bytes,
+                            mean_staleness=float(np.mean(stal)),
+                            max_staleness=int(max(stal))))
+                        if log_every and rnd % log_every == 0:
+                            # opt-in logging is the one place a fetch is
+                            # allowed
+                            print(f"  [SAFL-{cfg.aggregation}] round {rnd} "
+                                  f"acc={float(acc):.4f} "
+                                  f"loss={float(loss):.4f} "
+                                  f"stale={np.mean(stal):.2f}")
 
         # ---- the ONE device->host metrics transfer of the run ----
-        for fields, (acc, loss, unorm, nscr, nclip) in zip(pending,
-                                                           ring.flush()):
-            self.metrics.record(
-                accuracy=float(acc), loss=float(loss),
-                nan_event=not np.isfinite(loss),
-                update_norm=float(unorm),
-                screened_uploads=int(nscr), clipped_uploads=int(nclip),
-                **fields)
-        hist, part = ring.flush_sched()
-        self._dev_stale_hist += hist.astype(np.int64)
-        self._dev_participation += part.astype(np.int64)
+        with _span("safl.flush"):
+            for fields, (acc, loss, unorm, nscr, nclip) in zip(
+                    pending, ring.flush()):
+                self.metrics.record(
+                    accuracy=float(acc), loss=float(loss),
+                    nan_event=not np.isfinite(loss),
+                    update_norm=float(unorm),
+                    screened_uploads=int(nscr), clipped_uploads=int(nclip),
+                    **fields)
+            hist, part = ring.flush_sched()
+            self._dev_stale_hist += hist.astype(np.int64)
+            self._dev_participation += part.astype(np.int64)
+
+    def _gather_wave(self, w: int, cids: List[int], force_global: set,
+                     carry: Dict[int, Optional[int]], g_flat: jax.Array,
+                     g_state: Pytree, prev_new_flat: Optional[jax.Array],
+                     prev_states: Optional[Pytree]) -> tuple:
+        """A wave's start rows and per-client model states, one lane per
+        entry of ``cids`` (padding lanes included).  Returns ``(starts,
+        states, stacked)``; ``stacked`` counts the lanes assembled from
+        per-client arrays (0 when the wave starts from one broadcast or
+        gathered array)."""
+        kb = len(cids)
+        if w == 0:
+            starts = stack_rows([self._client_flats[cid] for cid in cids])
+            states = jax.tree_util.tree_map(
+                lambda *xs: jnp.stack(xs),
+                *[self.clients[cid].model_state for cid in cids])
+            return starts, states, kb
+        # a force_global lane restarts from the round-r global model (a
+        # reject/crash landed between this client's admitted events) —
+        # same row/state source as an adopting lane, so it reuses the
+        # None path
+        rows = [None if (cid, w) in force_global else carry.get(cid)
+                for cid in cids]
+        if all(rv is None for rv in rows):
+            # common case: every wave-0 member adopted the round-r global
+            # model
+            starts = jnp.broadcast_to(g_flat, (kb, self.codec.d))
+            states = jax.tree_util.tree_map(
+                lambda l: jnp.broadcast_to(l, (kb,) + l.shape), g_state)
+            return starts, states, 0
+        if all(rv is not None for rv in rows):
+            ridx = jnp.asarray(rows)
+            starts = prev_new_flat[ridx]
+            states = jax.tree_util.tree_map(lambda l: l[ridx], prev_states)
+            return starts, states, 0
+        # mixed: force_global lanes next to continuing local chains
+        # (mid-horizon crashes), or a future schedule the refresh rule
+        # doesn't cover
+        starts = stack_rows([g_flat if rv is None else prev_new_flat[rv]
+                             for rv in rows])
+        states = jax.tree_util.tree_map(
+            lambda *ls: jnp.stack(ls),
+            *[g_state if rv is None else jax.tree_util.tree_map(
+                lambda l, rv=rv: l[rv], prev_states)
+              for rv in rows])
+        return starts, states, kb
+
+    def _encode_wave(self, vecs: jax.Array, members: List[Tuple[int, int]],
+                     cids: List[int], evfaults: List,
+                     hfac: Optional[Dict[int, np.float32]],
+                     use_ef: bool) -> tuple:
+        """Serialize a wave into the server channel's wire format: the
+        stacked payload arrays ((vecs,) on f32, (q, s) on q8/q4, (idx,
+        qv, s) on topk), with error-feedback residuals written back,
+        payload faults applied and the defense screen's factors stored
+        in ``hfac`` by horizon slot."""
+        cfg = self.cfg
+        kw, kb = len(members), len(cids)
+        npad = kb - kw
+        new_res = None
+        if use_ef:
+            # padding lanes read member 0's pre-update residual (their
+            # outputs are discarded below)
+            res = jnp.stack([self._residual(cid) for cid in cids])
+        if self._quant:
+            if use_ef:
+                q, s, new_res = self.codec.quantize_rows(vecs, res)
+            else:
+                q, s = self.codec.quantize_rows_nores(vecs)
+            prows = (q, s)
+        elif self._q4:
+            # per-lane (cid, counter) PRNG keys; real lanes consume their
+            # client's next counter, padding lanes repeat lane 0's key
+            # (rows dropped either way)
+            ctrs = [self._next_counter(cid) for cid in cids[:kw]]
+            ctrs += [ctrs[0]] * npad
+            cids_v = jnp.asarray(cids, jnp.int32)
+            ctrs_v = jnp.asarray(ctrs, jnp.int32)
+            if use_ef:
+                q, s, new_res = self.codec.quantize_rows_q4(
+                    vecs, res, cfg.seed, cids_v, ctrs_v)
+            else:
+                q, s = self.codec.quantize_rows_q4_nores(
+                    vecs, cfg.seed, cids_v, ctrs_v)
+            prows = (q, s)
+        elif self._topk:
+            if use_ef:
+                ti, tq, ts, new_res = self.codec.quantize_rows_topk(vecs,
+                                                                    res)
+            else:
+                ti, tq, ts = self.codec.quantize_rows_topk_nores(vecs)
+            prows = (ti, tq, ts)
+        else:
+            prows = (vecs,)
+        if new_res is not None:
+            for row, cid in enumerate(cids[:kw]):
+                self._residuals[cid] = new_res[row]
+        # wire-level faults land on the serialized rows (the residuals
+        # above were already updated against the clean payload — the
+        # client believes it sent a good row); padding lanes carry no
+        # fault, and the appliers leave unfaulted lanes bitwise untouched
+        wfaults = [evfaults[slot] for slot, _ in members] + [None] * npad
+        prows = self._apply_payload_faults(prows, wfaults)
+        if hfac is not None:
+            # defense screen: one fused per-row pass over the wave
+            # (padding lanes screened but never counted); verdicts are
+            # keyed by horizon slot so the streaming fold consumes them
+            # in arrival order, exactly like the sequential path
+            fac = self._screen_factors(prows, kw)
+            for row, (slot, _cid) in enumerate(members):
+                hfac[slot] = fac[row]
+            if not self._streaming and bool((fac == np.float32(0.0)).any()):
+                mask = np.zeros(kb, bool)
+                mask[:kw] = fac == np.float32(0.0)
+                prows = self._zero_screened_rows(prows, mask)
+        return prows
+
+    def _fold_wave(self, prows: tuple, members: List[Tuple[int, int]],
+                   npad: int, pend: Dict[int, tuple], next_fold: int,
+                   wh, betah, hfac: Optional[Dict[int, np.float32]],
+                   stal: List[int]) -> Tuple[int, int, int]:
+        """Put a wave's payload rows into the server channel.  Returns
+        ``(next_fold, folds, skipped)``: the next horizon slot to fold
+        and the uploads this wave folded or skipped (both 0 on the
+        buffered channel, which writes the rows into its (K, D) buffer).
+        """
+        if not self._streaming:
+            # padding lanes get the first out-of-range slot: dropped by
+            # the scatter (write_rows mode="drop")
+            slots = np.asarray([slot for slot, _ in members]
+                               + [self._horizon_target] * npad, np.int32)
+            if self._quant or self._q4:
+                self._qbuf.write_rows(*prows, slots)
+            elif self._topk:
+                self._tbuf.write_rows(*prows, slots)
+            else:
+                self._buf = flatbuf.write_rows(self._buf, prows[0],
+                                               jnp.asarray(slots))
+            return next_fold, 0, 0
+        # hold-and-release: waves surface rows out of arrival order (wave
+        # 0 spans the whole horizon), but the sequential oracle folds in
+        # arrival order — so rows park in ``pend`` and fold strictly in
+        # slot order, which makes the batched fold chain the sequential
+        # one by construction (and keeps fedasync's non-commuting mix
+        # exact)
+        folds = skipped = 0
+        for row, (slot, _cid) in enumerate(members):
+            pend[slot] = tuple(a[row] for a in prows)
+        while next_fold in pend:
+            payload = pend.pop(next_fold)
+            fw = wh[next_fold]
+            if hfac is not None:
+                fv = hfac[next_fold]
+                if fv == np.float32(0.0):
+                    # screened: the fold is skipped outright (0 x NaN is
+                    # NaN) — skip() records the arrival with an exact 0.0
+                    # weight
+                    self._accum.skip(shard=self._fold_shard(next_fold),
+                                     staleness=stal[next_fold])
+                    next_fold += 1
+                    skipped += 1
+                    continue
+                fw = np.float32(fw * fv)
+            self._accum.fold(
+                payload, w=fw,
+                beta=(np.float32(1.0) - fw if betah is not None else 1.0),
+                shard=self._fold_shard(next_fold),
+                staleness=stal[next_fold])
+            next_fold += 1
+            folds += 1
+        return next_fold, folds, skipped
 
     # ---------- crash-consistent engine snapshots (PR 8) ----------
 

@@ -215,7 +215,7 @@ def main() -> None:
     ap.add_argument("--trace-jax", action="store_true",
                     help="additionally wrap the run in a jax.profiler "
                          "trace written into --trace-dir (XLA-level "
-                         "timing, viewable in Perfetto)")
+                         "timing, viewable in xprof or TensorBoard)")
     ap.add_argument("--json-out", default="")
     args = ap.parse_args()
     enable_compile_cache()

@@ -10,6 +10,9 @@
 - :mod:`repro.obs.profile` — jit compile-count tracking
   (``CompileLog``), host-transfer counting (``TransferScope``), and an
   optional ``jax.profiler`` toggle.
+- stage spans — ``FLEngine.run`` writes ``safl.*`` wall-clock spans
+  (``jax.profiler.TraceAnnotation``) into the profiler's trace, per
+  stage, joined to the simulated-clock tracer by the round index.
 - :mod:`repro.obs.report` — ``python -m repro.obs.report`` ASCII
   timeline CLI.
 

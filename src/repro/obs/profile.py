@@ -101,10 +101,6 @@ def record_transfer(tag: str) -> None:
     _TRANSFERS[str(tag)] += 1
 
 
-def transfer_counts() -> Dict[str, int]:
-    return dict(_TRANSFERS)
-
-
 class TransferScope:
     """Context manager measuring host transfers inside the scope::
 
