@@ -312,14 +312,63 @@ def make_batched_hetero_train(apply_fn: Callable, kind: str, target: str,
 
 
 @functools.lru_cache(maxsize=None)
-def _row_stacker(n: int):
-    """One-dispatch stack of n (D,) rows (``jnp.stack`` outside jit is an
-    expand_dims per operand + concat — ~n dispatches per wave)."""
-    return jax.jit(lambda *rows: jnp.stack(rows))
+def _state_stacker(n: int):
+    """One-dispatch stack of n trees, leaf by leaf: a wave's start rows
+    with their model states (``jnp.stack`` outside jit is an expand_dims
+    per operand + concat, n + 1 dispatches for every leaf)."""
+    return jax.jit(lambda *trees: jax.tree_util.tree_map(
+        lambda *ls: jnp.stack(ls), *trees))
 
 
-def stack_rows(rows) -> jax.Array:
-    return _row_stacker(len(rows))(*rows)
+@jax.jit
+def _take_states(tree: Pytree, idx: jax.Array) -> Pytree:
+    return jax.tree_util.tree_map(lambda l: l[idx], tree)
+
+
+def stack_states(trees) -> Pytree:
+    """``tree_map(lambda *ls: jnp.stack(ls), *trees)`` as one program for
+    the whole tree (a lane's ``(row, state)`` pair, or a state alone); an
+    empty tree (a model without BatchNorm) comes back as it is, with
+    nothing dispatched."""
+    if not jax.tree_util.tree_leaves(trees[0]):
+        return trees[0]
+    return _state_stacker(len(trees))(*trees)
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def broadcast_states(tree: Pytree, n: int) -> Pytree:
+    """n lanes of one tree (the global ``(row, state)``) as one program: a
+    broadcast, where a stack of n copies would also hold an n-lane temp
+    on the TPU."""
+    return jax.tree_util.tree_map(
+        lambda l: jnp.broadcast_to(l, (n,) + l.shape), tree)
+
+
+def take_states(tree: Pytree, idx) -> Pytree:
+    """``tree_map(lambda l: l[idx], tree)`` for a vector of rows ``idx``,
+    as one program for the whole tree (a state tree, or a ``(rows,
+    states)`` pair).  ``idx`` is a traced argument, so other rows of the
+    same count reuse the compiled program; an empty tree comes back as
+    it is, with nothing dispatched."""
+    if not jax.tree_util.tree_leaves(tree):
+        return tree
+    return _take_states(tree, np.asarray(idx, np.int32))
+
+
+@jax.jit
+def _split_states(tree: Pytree, idx: jax.Array) -> tuple:
+    return tuple(jax.tree_util.tree_map(lambda l: l[idx[i]], tree)
+                 for i in range(idx.shape[0]))
+
+
+def split_states(tree: Pytree, rows) -> list:
+    """``[tree_map(lambda l: l[row], tree) for row in rows]`` as one
+    program: one tree per row, the rows a traced argument (a new count
+    of rows compiles, new row values do not); an empty tree comes back
+    as it is, with nothing dispatched."""
+    if not rows or not jax.tree_util.tree_leaves(tree):
+        return [tree] * len(rows)
+    return list(_split_states(tree, np.asarray(rows, np.int32)))
 
 
 def cumulative_gradient(w_start: Pytree, w_end: Pytree, lr: float) -> Pytree:

@@ -133,7 +133,9 @@ from repro.core import flatbuf
 from repro.core.client import (ClientState, make_batched_hetero_train,
                                make_batched_local_train, make_eval_fn,
                                make_flat_eval_fn, make_local_train,
-                               pytree_bytes, resolve_wave_impl, stack_rows)
+                               pytree_bytes, resolve_wave_impl,
+                               broadcast_states, split_states,
+                               stack_states, take_states)
 from repro.core.metrics import DeviceMetricsRing, MetricsLog, RoundRecord
 from repro.kernels.quantize import payload_nbytes
 from repro.sharding import flat as shflat
@@ -1184,7 +1186,9 @@ class FLEngine:
                                      n_clients=len(self.clients))
         pending: List[Dict] = []  # host-side fields per recorded round
 
-        tree_stack = jax.tree_util.tree_map
+        # model-state leaves per client (the span stat `leaves`; 0 for a
+        # model without BatchNorm)
+        state_leaves = len(jax.tree_util.tree_leaves(self.global_state))
         self.sched.resume()
         while self.t_global < n_rounds:
             r = self.t_global
@@ -1323,7 +1327,8 @@ class FLEngine:
                             starts, states, stacked = self._gather_wave(
                                 w, cids, force_global, carry, g_flat,
                                 g_state, prev_new_flat, prev_states)
-                            sp.set_metadata(stacked=stacked)
+                            sp.set_metadata(stacked=stacked,
+                                            leaves=state_leaves)
                         with _span("safl.train"):
                             vecs, new_flat, new_states, _losses = wave_fn(
                                 starts, states, xs_all, ys_all, mask_all,
@@ -1340,16 +1345,18 @@ class FLEngine:
                         # ---- host bookkeeping + client refresh ----
                         with _span("safl.refresh") as sp:
                             sliced = 0
+                            chains = []  # (cid, row): continuing clients
                             # model targets on the quantized channel: the
                             # server-side state view is the int8
                             # roundtrip (identity otherwise)
                             up_states = (self._state_q8_rows(new_states)
                                          if target == "params"
                                          else new_states)
-                            state_parts.append(
-                                up_states if not npad
-                                else tree_stack(lambda l: l[:kw],
-                                                up_states))
+                            prefix = cfg.aggregation == "fedavg" and npad
+                            if cfg.aggregation == "fedavg":
+                                state_parts.append(
+                                    take_states(up_states, np.arange(kw))
+                                    if prefix else up_states)
                             for row, (slot, cid) in enumerate(members):
                                 c = self.clients[cid]
                                 self.tx_bytes += nbytes
@@ -1362,9 +1369,8 @@ class FLEngine:
                                         and cfg.aggregation != "fedavg"):
                                     # fedavg takes the weighted state
                                     # mean instead
-                                    last_slot_state = tree_stack(
-                                        lambda l, row=row: l[row],
-                                        up_states)
+                                    last_slot_state, = split_states(
+                                        up_states, [row])
                                     sliced += 1
                                 # refresh rule (paper §2.2.2): adopt the
                                 # round-r global model iff one arrived
@@ -1378,11 +1384,15 @@ class FLEngine:
                                     flats[cid] = g_flat
                                     c.model_state = g_state
                                 else:
-                                    flats[cid] = new_flat[row]
-                                    c.model_state = tree_stack(
-                                        lambda l, row=row: l[row],
-                                        new_states)
-                                    sliced += 1
+                                    chains.append((cid, row))
+                            # the continuing clients' rows and states,
+                            # all in one program
+                            ends = split_states((new_flat, new_states),
+                                                [row for _, row in chains])
+                            for (cid, _), (flat, state) in zip(chains, ends):
+                                flats[cid] = flat
+                                self.clients[cid].model_state = state
+                            sliced += len(chains)
                             prev_new_flat, prev_states = new_flat, new_states
                             if w == len(waves) - 1:
                                 # reject/crash resets that landed after a
@@ -1395,7 +1405,10 @@ class FLEngine:
                                     c = self.clients[cid]
                                     c.model_state = g_state
                                     c.version = r
-                            sp.set_metadata(sliced=sliced)
+                            sp.set_metadata(
+                                sliced=sliced,
+                                leaves=state_leaves if sliced or prefix
+                                else 0)
 
                 # ---- fused server round (no host sync) ----
                 facs = ([hfac[i] for i in range(kh)]
@@ -1433,7 +1446,7 @@ class FLEngine:
                 with _span("safl.state"):
                     if cfg.aggregation == "fedavg":
                         stacked = (state_parts[0] if len(state_parts) == 1
-                                   else tree_stack(
+                                   else jax.tree_util.tree_map(
                                        lambda *xs: jnp.concatenate(xs),
                                        *state_parts))
                         if jax.tree_util.tree_leaves(stacked):
@@ -1492,10 +1505,9 @@ class FLEngine:
         gathered array)."""
         kb = len(cids)
         if w == 0:
-            starts = stack_rows([self._client_flats[cid] for cid in cids])
-            states = jax.tree_util.tree_map(
-                lambda *xs: jnp.stack(xs),
-                *[self.clients[cid].model_state for cid in cids])
+            starts, states = stack_states(
+                [(self._client_flats[cid], self.clients[cid].model_state)
+                 for cid in cids])
             return starts, states, kb
         # a force_global lane restarts from the round-r global model (a
         # reject/crash landed between this client's admitted events) —
@@ -1506,25 +1518,20 @@ class FLEngine:
         if all(rv is None for rv in rows):
             # common case: every wave-0 member adopted the round-r global
             # model
-            starts = jnp.broadcast_to(g_flat, (kb, self.codec.d))
-            states = jax.tree_util.tree_map(
-                lambda l: jnp.broadcast_to(l, (kb,) + l.shape), g_state)
+            starts, states = broadcast_states((g_flat, g_state), kb)
             return starts, states, 0
+        prev = (prev_new_flat, prev_states)
         if all(rv is not None for rv in rows):
-            ridx = jnp.asarray(rows)
-            starts = prev_new_flat[ridx]
-            states = jax.tree_util.tree_map(lambda l: l[ridx], prev_states)
+            starts, states = take_states(prev, rows)
             return starts, states, 0
         # mixed: force_global lanes next to continuing local chains
         # (mid-horizon crashes), or a future schedule the refresh rule
         # doesn't cover
-        starts = stack_rows([g_flat if rv is None else prev_new_flat[rv]
-                             for rv in rows])
-        states = jax.tree_util.tree_map(
-            lambda *ls: jnp.stack(ls),
-            *[g_state if rv is None else jax.tree_util.tree_map(
-                lambda l, rv=rv: l[rv], prev_states)
-              for rv in rows])
+        carried = iter(split_states(prev, [rv for rv in rows
+                                           if rv is not None]))
+        starts, states = stack_states(
+            [(g_flat, g_state) if rv is None else next(carried)
+             for rv in rows])
         return starts, states, kb
 
     def _encode_wave(self, vecs: jax.Array, members: List[Tuple[int, int]],

@@ -9,7 +9,11 @@ profiler on the CPU; the ``.xplane.pb`` is read back and checked for:
     ``lanes`` sum to K;
   * the span tree: every stage lies inside its parent;
   * the ``round`` stat joins the SpanTracer's round records;
-  * the profiler changes nothing the engine computes.
+  * the profiler changes nothing the engine computes;
+  * ``safl.gather`` and ``safl.refresh`` move a model's whole state tree
+    in one program: on ResNet-18 (40 BatchNorm leaves) at most two
+    programs run inside either span, and ``leaves`` reads the tree's
+    leaf count (0 on the CNN).
 """
 import glob
 
@@ -25,6 +29,9 @@ from repro.models.vision_cnn import build_paper_model
 from repro.obs.profile import jax_profile
 
 K, ROUNDS = 3, 4
+
+#: the host event of one program execution on jaxlib's CPU client
+EXECUTE = "PjRtCpuExecutable::Execute"
 
 #: each stage span and the span it lies inside
 PARENT = {"safl.ring": "safl.run", "safl.round": "safl.run",
@@ -65,13 +72,23 @@ def _stage_spans(path):
     return sorted(out, key=lambda s: (s[1], -s[2]))
 
 
-@pytest.fixture(scope="module")
-def traced(tmp_path_factory):
+def _executions(path):
+    """Start times of the programs executed on the host's CPU client."""
+    return sorted(int(e.start_ns)
+                  for plane in ProfileData.from_file(path).planes
+                  if plane.name.startswith("/host:")
+                  for line in plane.lines for e in line.events
+                  if e.name == EXECUTE)
+
+
+def _trace(model, tmp_path_factory, **kw):
+    """``(engine, result, untraced result, spans, executions)`` of the
+    tiny engine on ``model``, traced after an untraced run compiled its
+    programs."""
     ds = make_dataset("cifar10", n=240, seed=0, hw=16)
     tr, te = train_test_split(ds)
     shards = build_client_shards(tr, "iid", n_clients=6, batch_size=16)
-    p0, s0, apply_fn = build_paper_model("cnn", jax.random.PRNGKey(0),
-                                         width=4, image_size=16)
+    p0, s0, apply_fn = build_paper_model(model, jax.random.PRNGKey(0), **kw)
     setup = (shards, te, p0, s0, apply_fn)
     plain = _engine(setup)
     res_plain = _drive(plain)
@@ -80,7 +97,17 @@ def traced(tmp_path_factory):
     with jax_profile(trace_dir):
         res = _drive(eng)
     path, = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
-    return eng, res, res_plain, _stage_spans(path)
+    return eng, res, res_plain, _stage_spans(path), _executions(path)
+
+
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    return _trace("cnn", tmp_path_factory, width=4, image_size=16)
+
+
+@pytest.fixture(scope="module", params=[2, 4], ids=["w2", "w4"])
+def traced_resnet(request, tmp_path_factory):
+    return _trace("resnet18", tmp_path_factory, width=request.param)
 
 
 def _named(spans, name):
@@ -92,7 +119,7 @@ def _inside(child, parent):
 
 
 def test_one_round_span_per_round_with_k_uploads(traced):
-    _eng, _res, _plain, spans = traced
+    _eng, _res, _plain, spans, _execs = traced
     rounds = _named(spans, "safl.round")
     assert [s[3]["round"] for s in rounds] == list(range(1, ROUNDS + 1))
     assert all(s[3]["uploads"] == K for s in rounds)
@@ -112,7 +139,7 @@ def test_one_round_span_per_round_with_k_uploads(traced):
 
 
 def test_stage_spans_nest(traced):
-    _eng, _res, _plain, spans = traced
+    _eng, _res, _plain, spans, _execs = traced
     runs = _named(spans, "safl.run")
     assert [s[3]["rounds"] for s in runs] == [2, ROUNDS - 2]
     assert set(PARENT) <= {s[0] for s in spans}
@@ -127,13 +154,13 @@ def test_stage_spans_nest(traced):
 
 
 def test_round_stat_joins_the_span_tracer(traced):
-    eng, _res, _plain, spans = traced
+    eng, _res, _plain, spans, _execs = traced
     sim = [r["round"] for r in eng.tracer.records if r.get("name") == "round"]
     assert sim == [s[3]["round"] for s in _named(spans, "safl.round")]
 
 
 def test_profiler_changes_nothing_the_engine_computes(traced):
-    _eng, res, plain, _spans = traced
+    _eng, res, plain, _spans, _execs = traced
     for a, b in zip(jax.tree_util.tree_leaves(res.final_params),
                     jax.tree_util.tree_leaves(plain.final_params),
                     strict=True):
@@ -141,3 +168,51 @@ def test_profiler_changes_nothing_the_engine_computes(traced):
     assert res.metrics.records == plain.metrics.records
     assert len(res.metrics.records) == ROUNDS
 
+
+
+def _programs_in(span, execs):
+    return sum(span[1] <= t <= span[2] for t in execs)
+
+
+def _state_stage_programs(spans, execs):
+    """Programs run inside each ``safl.gather`` and ``safl.refresh`` span,
+    beside the span's stats; fails loudly when the trace holds no
+    execution event to count."""
+    assert execs, f"no {EXECUTE} event in the trace: nothing to count"
+    return [(s[0], _programs_in(s, execs), s[3]) for s in spans
+            if s[0] in ("safl.gather", "safl.refresh")]
+
+
+def test_state_stages_run_one_program_per_move_on_resnet(traced_resnet):
+    """A gather stacks the lanes' rows and states in one program; a
+    refresh is the last slot's state slice and one program for every
+    continuing client's row and state.  Neither grows with the tree's 40
+    leaves nor with the lanes sliced (the schedule slices up to four in
+    a wave)."""
+    _eng, _res, _plain, spans, execs = traced_resnet
+    stages = _state_stage_programs(spans, execs)
+    assert {name for name, _, _ in stages} == {"safl.gather",
+                                                "safl.refresh"}
+    for name, programs, stats in stages:
+        moved = name == "safl.gather" or stats["sliced"] > 0
+        assert stats["leaves"] == (40 if moved else 0), (name, stats)
+        if name == "safl.gather":
+            assert programs == 1, (programs, stats)
+        else:
+            assert programs <= min(2, stats["sliced"]), (programs, stats)
+    assert max(stats.get("sliced", 0) for _, _, stats in stages) > 2
+
+
+def test_state_stages_of_a_stateless_model_move_no_leaves(traced):
+    """The CNN has no BatchNorm: ``leaves`` reads 0, and each gather and
+    refresh runs one program at most, the one that stacks or slices the
+    rows (its empty state trees ride along in the same program)."""
+    _eng, _res, _plain, spans, execs = traced
+    stages = _state_stage_programs(spans, execs)
+    assert {name for name, _, _ in stages} == {"safl.gather",
+                                                "safl.refresh"}
+    for name, programs, stats in stages:
+        assert stats["leaves"] == 0, (name, stats)
+        assert programs <= 1, (name, programs, stats)
+    assert all(programs == 1 for name, programs, _ in stages
+               if name == "safl.gather")
