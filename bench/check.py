@@ -5,9 +5,10 @@ them most uploads come from clients that uploaded before and trained
 from a global model they adopted; a run in which the reference counts
 none of these is not correct.
 
-Eight numbers, each a relative gap between two readings of the same
-quantity (never the norm of a difference, so client training's
-sensitivity to rounding does not count against the program):
+Nine numbers (the last only where the wire keeps residuals), each a
+relative gap between two readings of the same quantity (never the norm
+of a difference, so client training's sensitivity to rounding does not
+count against the program):
 
 * ``loss``: the largest gap, over the checked rounds, between the
   program's evaluation loss and the reference's, over the reference's.
@@ -30,6 +31,17 @@ sensitivity to rounding does not count against the program):
   is larger on every round (the mean of fewer uploads keeps more of their
   spread), so this sees that fault where the uploads agree closely.
 
+* ``residual`` (on a wire with error feedback): the error-feedback
+  residual each client holds after the checked rounds, by the worst
+  client: ``|norm(program's) - norm(reference's)|`` over the larger of
+  the two, over every client either side holds one for.  A residual is
+  what the wire dropped from the client's last upload; the global
+  weights cannot show it, since the quantisation error of an upload moves
+  a leaf's norm by far less than the program's rounding does.  A program
+  that keeps no residual reads 1 on every client, and so does one that
+  zeroes a residual where the reference keeps it.  Absent where neither
+  side holds a residual.
+
 Leaves whose first-round update in the reference is under a thousandth
 of the median leaf's are left out of both (none is at present; the rule
 is there for weights that only round-off moves).
@@ -43,7 +55,7 @@ import numpy as np
 NEGLIGIBLE = 1e-3
 #: the numbers compared, in the order they are printed
 NUMBERS = ("loss", "grad", "grad_med", "last", "last_med", "change",
-           "change_med", "drift_med")
+           "change_med", "drift_med", "residual")
 
 
 def _leaves(tree) -> dict:
@@ -87,11 +99,22 @@ def _drift(pp: list, rp: list, keep: list) -> float:
     return abs(float(np.mean(per_round)))
 
 
+def _residual_gap(prog: dict, ref: dict) -> float:
+    """The worst client's gap between the norms of its residual in the
+    program and in the reference (a missing residual reads 0)."""
+    gaps = [abs(prog.get(c, 0.0) - ref.get(c, 0.0))
+            / max(prog.get(c, 0.0), ref.get(c, 0.0), 1e-30)
+            for c in set(prog) | set(ref)]
+    return max(gaps)
+
+
 def readings(prog: dict, ref: dict) -> dict:
     """``prog`` and ``ref``: ``params`` (global weights before round 1 and
     after each checked round) and ``losses`` (after each checked round);
-    ``ref`` also counts ``reuploads`` and ``adopted`` uploads.  Returns
-    each compared number with the leaf that set it, and those counts."""
+    ``ref`` also counts ``reuploads`` and ``adopted`` uploads.  Either may
+    hold ``residuals``, the norms of the clients' error-feedback residuals
+    by client id.  Returns each compared number with the leaf that set
+    it, and those counts."""
     pp = [_leaves(t) for t in prog["params"]]
     rp = [_leaves(t) for t in ref["params"]]
     r = len(rp) - 1
@@ -114,7 +137,12 @@ def readings(prog: dict, ref: dict) -> dict:
     out["change"], out["change_leaf"], out["change_med"] = _leaf_gaps(
         _diff(pp[r], pp[0]), _diff(rp[r], rp[0]), keep)
     out["drift_med"] = _drift(pp, rp, keep)
+    res_p, res_r = prog.get("residuals", {}), ref.get("residuals", {})
+    if res_p or res_r:
+        out["residual"] = _residual_gap(res_p, res_r)
     for key in NUMBERS:
+        if key not in out:
+            continue
         if not np.isfinite(out[key]) or not np.all(np.isfinite(lr)):
             out[key] = float("inf")
     out["left_out"] = len(norms) - len(keep)
@@ -132,6 +160,8 @@ def verdict(read: dict, limits: dict | None) -> tuple:
     rows = [("adopted", read["adopted"], 1)]
     ok = limits is not None and read["adopted"] >= 1
     for name in NUMBERS:
+        if name not in read:  # not read in this cell
+            continue
         lim = None if limits is None else limits["limits"].get(name)
         val = read[name]
         rows.append((name, val, lim))

@@ -1,5 +1,5 @@
-"""ResNet-18 (CIFAR variant) for the benchmark: weights from a seed, the
-plain reference forward pass, and the model FLOPs from shapes.
+"""ResNet-18 (CIFAR variant) for the benchmark: data and weights from a
+seed, the plain reference forward pass, and the model FLOPs from shapes.
 
 Nothing here imports the program.  ``init`` lays the weights out in the
 dict layout the program's ``resnet18_apply`` reads (``stem``, ``bn0``,
@@ -16,7 +16,17 @@ shape changes, global average pooling and one dense layer.
 """
 from __future__ import annotations
 
+import os
+import sys
+
 import numpy as np
+
+_BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if _BENCH not in sys.path:
+    sys.path.insert(0, _BENCH)
+
+# the inputs and targets: synthetic CIFAR-10 (``bench/cifar.py``)
+from cifar import make_data  # noqa: E402,F401
 
 
 def _blocks(cfg):
@@ -72,8 +82,9 @@ def init(cfg, key):
     return params, state
 
 
-def apply(cfg, params, state, x, train):
-    """Plain forward pass: (logits, new running statistics)."""
+def apply(cfg, params, state, x, train, frozen=None):
+    """Plain forward pass: (logits, new running statistics).  Nothing is
+    frozen: every weight is trained."""
     import jax
     import jax.numpy as jnp
 
@@ -138,3 +149,9 @@ def flops_forward(cfg):
             total += _conv_flops(hw, st, 1, cin, cout)[0]
         hw = hw_out
     return float(total + 2 * _blocks(cfg)[-1][2] * cfg["n_classes"])
+
+
+def flops_train(cfg):
+    """Model FLOPs of one sample's training step: forward, input
+    gradients and weight gradients, three forward passes' worth."""
+    return 3.0 * flops_forward(cfg)

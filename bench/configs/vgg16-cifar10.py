@@ -1,5 +1,5 @@
-"""VGG-16 (configuration D) with the 32x32 head, for the benchmark:
-weights from a seed, the plain reference forward pass, and the model
+"""VGG-16 (configuration D) with the 32x32 head, for the benchmark: data
+and weights from a seed, the plain reference forward pass, and the model
 FLOPs from shapes.
 
 Nothing here imports the program.  ``init`` lays the weights out in the
@@ -12,7 +12,17 @@ pools after each stage, no BatchNorm; the head is the SAFL paper's
 """
 from __future__ import annotations
 
+import os
+import sys
+
 import numpy as np
+
+_BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if _BENCH not in sys.path:
+    sys.path.insert(0, _BENCH)
+
+# the inputs and targets: synthetic CIFAR-10 (``bench/cifar.py``)
+from cifar import make_data  # noqa: E402,F401
 
 
 def _convs(cfg):
@@ -49,8 +59,9 @@ def init(cfg, key):
     return params, {}
 
 
-def apply(cfg, params, state, x, train):
-    """Plain forward pass: (logits, state unchanged: VGG has no BN)."""
+def apply(cfg, params, state, x, train, frozen=None):
+    """Plain forward pass: (logits, state unchanged: VGG has no BN).
+    Nothing is frozen: every weight is trained."""
     import jax
     import jax.numpy as jnp
 
@@ -89,3 +100,9 @@ def flops_forward(cfg):
     dims = [hw * hw * cin] + list(cfg["head"]) + [cfg["n_classes"]]
     total += sum(2 * a * b for a, b in zip(dims, dims[1:]))
     return float(total)
+
+
+def flops_train(cfg):
+    """Model FLOPs of one sample's training step: forward, input
+    gradients and weight gradients, three forward passes' worth."""
+    return 3.0 * flops_forward(cfg)

@@ -1,7 +1,7 @@
 """Readings that set the limits of the correctness comparison, several
 seeds in one process (one compile), at a cell's own size.
 
-    python3 bench/control.py --workload resnet18.as-f32 --seeds 1 2 3 \\
+    python3 bench/control.py --workload <cell> --seeds 1 2 3 \\
         --program --control --faults --witness
 
 For each seed it prints one JSON line of readings (the numbers of
@@ -16,6 +16,12 @@ For each seed it prints one JSON line of readings (the numbers of
   negated where the client produces it, with every server step returning
   the global weights unchanged, or with no client ever adopting a
   published global model, in the program's place;
+* and, on a cell whose wire keeps error-feedback residuals, the faults
+  of that wire: ``no_ef`` and ``f32_wire``, the program with
+  ``error_feedback`` off and on the f32 wire; ``no_feedback`` and
+  ``reset_residual``, the reference keeping each residual but never
+  adding it to the next upload, and zeroing it when the client adopts a
+  global model, in the program's place;
 * ``witness``: two readings of where the program's gap to the reference
   comes from: ``program_highest``, the program with every matmul and
   convolution at ``highest`` precision, and ``ref_default``, the
@@ -39,6 +45,20 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 import check  # noqa: E402
 import harness  # noqa: E402
 import reference  # noqa: E402
+
+
+#: the program's settings that plant each fault of the wire in it
+PROGRAM_WIRE_FAULTS = {"no_ef": {"error_feedback": False},
+                       "f32_wire": {"wire": "f32"}}
+#: the faults of the wire planted in the reference
+REFERENCE_WIRE_FAULTS = ("no_feedback", "reset_residual")
+
+
+def with_engine(cell: dict, **change) -> dict:
+    """The cell with its engine settings changed."""
+    traffic = dict(cell["traffic"])
+    traffic["engine"] = {**traffic["engine"], **change}
+    return {**cell, "traffic": traffic}
 
 
 def _program_rounds(cell: dict, seed: int, data: dict, n: int) -> dict:
@@ -68,6 +88,13 @@ def seed_readings(cell: dict, seed: int, *, program: bool, control: bool,
     if witness:
         with jax.default_matmul_precision("highest"):
             prog_hi = _program_rounds(cell, seed, data, n)
+    wire = faults and traffic["engine"].get("error_feedback", False) \
+        and traffic["engine"].get("wire", "f32") != "f32"
+    planted = {}
+    if wire:
+        for name, change in PROGRAM_WIRE_FAULTS.items():
+            planted[name] = _program_rounds(with_engine(cell, **change),
+                                            seed, data, n)
     t0 = time.perf_counter()
     ref = reference.run(cfg, cell["ref"], traffic, pop, data,
                         harness.make_weights(cell, seed), n)
@@ -77,6 +104,8 @@ def seed_readings(cell: dict, seed: int, *, program: bool, control: bool,
         out["program"] = check.readings(prog, ref)
     if witness:
         out["program_highest"] = check.readings(prog_hi, ref)
+    for name, other in planted.items():
+        out[name] = check.readings(other, ref)
     variants = []
     if control:
         variants.append(("control", dict(dtype="bfloat16",
@@ -85,6 +114,8 @@ def seed_readings(cell: dict, seed: int, *, program: bool, control: bool,
         variants += [(f, dict(fault=f))
                      for f in ("drop_half", "alter_one", "unchanged",
                                "no_adopt")]
+    if wire:
+        variants += [(f, dict(fault=f)) for f in REFERENCE_WIRE_FAULTS]
     if witness:
         variants.append(("ref_default", dict(precision="default")))
     for name, kw in variants:

@@ -10,6 +10,33 @@ configuration and a traffic mix.  The configuration is
 the limits of the correctness comparison are ``bench/limits/<cell>.json``.
 Adding a cell adds files; nothing here names a cell.
 
+A configuration's JSON names its module (``reference``, a path from the
+checkout's root), the program's model function (``program_apply``,
+``program_kwargs``) and its ``kind``, and holds every size its module
+reads.  The module says what the configuration's inputs, targets,
+weights and work are; it imports nothing of the program and provides:
+
+* ``make_data(cfg, traffic, key) -> dict``: ``xs`` and ``ys`` as
+  (clients, batches, batch, ...) and ``test_x``, ``test_y``, as host
+  arrays, from a JAX key.  ``ys`` holds one label per sample or one
+  target per position; targets come already shifted (a next-token
+  target sits at its input's position), so no shift lives in the
+  reference.  Integer inputs are token ids and stay integers.
+* ``init(cfg, key)``: ``(params, state)`` or ``(params, state,
+  frozen)``, jittable.  ``params`` is the trained part: what a client
+  trains and uploads, the server aggregates and ``check.py`` compares;
+  its element count is the uploaded row's length (``work.row_length``).
+  ``state`` is the non-trained model state (BatchNorm statistics).
+  ``frozen`` is shared by every client and never trained, uploaded,
+  averaged or copied per client; ``build_engine`` hands it to the
+  engine as ``FLEngine(..., frozen=frozen)`` only where it exists.
+* ``apply(cfg, params, state, x, train, frozen=None)``: the plain
+  forward pass, ``(logits (..., classes), new state)``; the reference
+  passes ``frozen`` in training and in evaluation.
+* ``flops_forward(cfg)`` and ``flops_train(cfg)``: model FLOPs of one
+  sample's forward pass and of its training step, from shapes held in
+  the configuration file (``work.round_flops``).
+
 The traffic generator gives every seed the same population: client
 speeds, link times and start offsets are fixed quantiles of their laws
 (lognormal, lognormal, uniform), in a fixed pairing, and the seed only
@@ -61,13 +88,23 @@ def benchmark() -> dict:
     return read_json(os.path.join(ROOT, "BENCHMARK.json"))
 
 
+@functools.lru_cache(maxsize=None)
+def _config_module(path: str):
+    stem = os.path.splitext(os.path.basename(path))[0]
+    return load_module(path, "bench_config_" + stem.replace("-", "_"))
+
+
+def config_module(cfg: dict):
+    """The plain reference module a configuration names (``reference``,
+    relative to the checkout's root), imported once per process."""
+    return _config_module(os.path.join(ROOT, cfg["reference"]))
+
+
 def find_config(name: str) -> tuple:
     """A configuration's sizes (``bench/configs/<name>.json``) and its
     plain reference module (``bench/configs/<name>.py``), by name."""
     cfg = read_json(os.path.join(BENCH, "configs", name + ".json"))
-    ref = load_module(os.path.join(BENCH, "configs", name + ".py"),
-                      "bench_config_" + name.replace("-", "_"))
-    return cfg, ref
+    return cfg, config_module(cfg)
 
 
 def find_cell(name: str, bench: dict | None = None) -> dict:
@@ -136,57 +173,19 @@ class StartOffset:
         return self.offset
 
 
-@functools.lru_cache(maxsize=None)
-def _data_fn(n_train: int, n_test: int, hw: int, ch: int, n_classes: int):
-    import jax
-    import jax.numpy as jnp
-
-    @jax.jit
-    def make(key):
-        kt, ky, kn, ks, kyt, knt, kst = jax.random.split(key, 7)
-        t = jax.random.normal(kt, (n_classes, hw, hw, ch), jnp.float32)
-        for _ in range(2):  # low-frequency class templates
-            t = (t + jnp.roll(t, 1, 1) + jnp.roll(t, -1, 1)
-                 + jnp.roll(t, 1, 2) + jnp.roll(t, -1, 2)) / 5.0
-        t = t / jnp.std(t)
-
-        def draw(kyy, knn, kss, n):
-            y = jax.random.randint(kyy, (n,), 0, n_classes, jnp.int32)
-            x = (t[y] + 0.35 * jax.random.normal(knn, (n, hw, hw, ch))
-                 + 0.1 * jax.random.normal(kss, (n, 1, 1, ch)))
-            return x.astype(jnp.float32), y
-
-        x, y = draw(ky, kn, ks, n_train)
-        xt, yt = draw(kyt, knt, kst, n_test)
-        return x, y, xt, yt
-
-    return make
-
-
 def make_data(cfg: dict, traffic: dict, seed: int) -> dict:
-    """CIFAR-shaped data from the seed, made on the device in one call:
-    ``xs``/``ys`` per client as (clients, batches, batch, ...) and the
-    evaluation set.  Returned as host arrays (the engine's input)."""
+    """The configuration's inputs and targets from the seed's data
+    sub-seed (slot 0), made by its module's ``make_data``."""
     import jax
 
-    pop = traffic["population"]
-    n, per = int(pop["n_clients"]), int(pop["samples_per_client"])
-    b = int(traffic["engine"]["local_batch_size"])
-    assert per % b == 0, (per, b)
-    n_eval = int(traffic["eval_samples"])
-    assert n * per <= cfg["train_samples"], (n * per, cfg["train_samples"])
-    assert n_eval <= cfg["test_samples"], (n_eval, cfg["test_samples"])
-    hw, ch = cfg["image_size"], cfg["in_channels"]
     key = jax.random.PRNGKey(sub_seeds(seed, 3)[0])
-    x, y, xt, yt = jax.device_get(
-        _data_fn(n * per, n_eval, hw, ch, cfg["n_classes"])(key))
-    return dict(xs=x.reshape(n, per // b, b, hw, hw, ch),
-                ys=y.reshape(n, per // b, b), test_x=xt, test_y=yt)
+    return config_module(cfg).make_data(cfg, traffic, key)
 
 
 def make_weights(cell: dict, seed: int):
-    """(params, state) from the seed, made on the device in one call, in
-    the layout the program and the reference both read."""
+    """``init``'s (params, state) or (params, state, frozen) from the
+    seed, made on the device in one call, in the layout the program and
+    the reference both read."""
     import jax
 
     key = jax.random.PRNGKey(sub_seeds(seed, 3)[1])
@@ -231,12 +230,13 @@ def build_engine(cell: dict, seed: int, data: dict, weights):
     shards = [dict(xs=data["xs"][i], ys=data["ys"][i],
                    mask=np.ones((nb, b), np.float32), n=per)
               for i in range(pop["n"])]
-    params, state = weights
+    params, state, *frozen = weights
     apply_fn = program_apply(cfg["program_apply"],
                              json.dumps(cfg["program_kwargs"],
                                         sort_keys=True))
+    extra = dict(frozen=frozen[0]) if frozen else {}
     eng = FLEngine(fl, apply_fn, cfg["kind"], params, state, shards,
-                   data["test_x"], data["test_y"])
+                   data["test_x"], data["test_y"], **extra)
     assert abs(pop["base_rate"] * eng.clients[0].speed
                * eng._base_compute(eng.clients[0])
                - per * fl.local_epochs) < 1e-6 * per, \
@@ -254,10 +254,29 @@ def run_round(eng):
     return eng.run(eng.t_global + 1)
 
 
+@functools.lru_cache(maxsize=None)
+def _norm():
+    import jax
+    import jax.numpy as jnp
+
+    return jax.jit(lambda r: jnp.linalg.norm(r.astype(jnp.float32)))
+
+
+def residual_norms(eng) -> dict:
+    """The norm of each client's error-feedback residual the engine holds
+    (``FLEngine._residuals``), by client id: none off a lossy wire."""
+    import jax
+
+    held = getattr(eng, "_residuals", {})
+    norms = jax.device_get({int(c): _norm()(r) for c, r in held.items()})
+    return {c: float(v) for c, v in norms.items()}
+
+
 def checked_rounds(eng, n: int, p0) -> dict:
     """Drive the engine through its first ``n`` rounds (the rounds the
     reference replays) and record the global weights before and after
-    each (host pytrees) and each round's evaluation loss."""
+    each (host pytrees), each round's evaluation loss, and the norms of
+    the error-feedback residuals the clients hold after the last."""
     import jax
 
     out = dict(params=[p0], losses=[])
@@ -265,6 +284,7 @@ def checked_rounds(eng, n: int, p0) -> dict:
         res = run_round(eng)
         out["params"].append(jax.device_get(res.final_params))
         out["losses"].append(float(res.metrics.records[-1].loss))
+    out["residuals"] = residual_norms(eng)
     return out
 
 

@@ -71,7 +71,8 @@ def context(cell: dict, tr: dict, peak: dict) -> dict:
         busy_s=sum(busy) / len(busy) / 1e9 if busy else 0.0, peak=peak,
         round_flops=work.round_flops(cfg, cell["ref"], traffic),
         uploads_per_round=int(eng["k"]),
-        fold_bytes=work.fold_bytes(cfg["n_params"], eng.get("wire", "f32"),
+        fold_bytes=work.fold_bytes(work.row_length(cfg, cell["ref"]),
+                                   eng.get("wire", "f32"),
                                    int(eng.get("quant_block", 512))))
 
 

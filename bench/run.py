@@ -1,7 +1,7 @@
 """Chip benchmark of the SAFL engine (``FLEngine`` on the batched
 semi-asynchronous path, streaming server channel).
 
-    python3 bench/run.py --workload resnet18.as-f32 --seed 7 --seconds 30 --trace 0
+    python3 bench/run.py --workload <cell> --seed 7 --seconds 30 --trace 0
 
 The window runs the loop of a researcher who reads every round's accuracy
 as it lands: one aggregation round per ``FLEngine.run`` call, each timed
@@ -131,13 +131,17 @@ def measure(args, bench: dict, cell: dict, peak: dict) -> int:
 
     # ---- set-up: inputs, engine, checked rounds, warm-up ----
     cfg, traffic = cell["cfg"], cell["traffic"]
+    stages = [("start", T_START), ("jax", time.perf_counter())]
     data = harness.make_data(cfg, traffic, args.seed)
     weights = harness.make_weights(cell, args.seed)
     p0 = jax.device_get(weights[0])
+    stages.append(("inputs", time.perf_counter()))
     eng = harness.build_engine(cell, args.seed, data, weights)
     del weights
+    stages.append(("engine", time.perf_counter()))
     n_checked = int(traffic["checked_rounds"])
     prog = harness.checked_rounds(eng, n_checked, p0)
+    stages.append(("checked", time.perf_counter()))
     harness.prewarm_ring(int(traffic["max_rounds"]))
     quiet, warm = 0, 0
     while quiet < 3:
@@ -148,9 +152,13 @@ def measure(args, bench: dict, cell: dict, peak: dict) -> int:
         if warm > 50:
             return fail(f"programs still compile after {warm} warm-up "
                         f"rounds: {counter.names[before:]}")
+    stages.append(("warm-up", time.perf_counter()))
     setup_s = time.perf_counter() - T_START
     log(f"set-up {setup_s:.3f}s: {n_checked} checked + {warm} warm-up "
         f"rounds, {len(counter)} programs compiled or loaded")
+    log("set-up stages: " + ", ".join(
+        f"{name} {t - stages[i][1]:.3f}s"
+        for i, (name, t) in enumerate(stages[1:])))
 
     # ---- the measured window ----
     compiled_before = len(counter)

@@ -2,15 +2,17 @@
 ``bench/control.py`` wrote, by one fixed rule, and writes
 ``bench/limits/<cell>.json``.
 
-    python3 bench/setlimits.py --workload resnet18.as-f32 readings.jsonl ...
+    python3 bench/setlimits.py --workload <cell> readings.jsonl ...
 
 For each compared number (``bench/check.py``):
 
 * lower reading: the largest the program reads over the seeds;
 * upper reading: the smallest that a variant which must fail reads over
   the seeds, among the variants that read far enough above the lower
-  reading to count: the bfloat16 control and a state left unchanged at
-  three times the lower reading or more, every other planted fault
+  reading to count: the bfloat16 control, a state left unchanged and a
+  wire fault that keeps no residual or zeroes one at three times the
+  lower reading or more (the last two read 1, the most a gap can, by
+  construction and not by noise), every other planted fault
   (``no_adopt`` included) at ten times or more;
 * limit: ``lower^0.4 x upper^0.6``, rounded down to two digits, so there
   is more room above the lower reading than below the upper one; null
@@ -37,11 +39,14 @@ import harness  # noqa: E402
 #: variants that must fail, and the least multiple of the lower reading
 #: at which each sets an upper reading
 MUST_FAIL = {"control": 3.0, "unchanged": 3.0, "drop_half": 10.0,
-             "alter_one": 10.0}
+             "alter_one": 10.0, "no_ef": 3.0, "f32_wire": 3.0,
+             "reset_residual": 3.0}
 #: planted faults that set an upper reading the same way, read and
 #: reported, but not required to fail: a client that never adopts the
-#: global model moves the checked rounds by little (PERF.md)
-ALSO_READ = {"no_adopt": 10.0}
+#: global model moves the checked rounds by little, and a residual kept
+#: but never added back changes neither what a client holds nor the
+#: global weights by more than rounding (PERF.md)
+ALSO_READ = {"no_adopt": 10.0, "no_feedback": 10.0}
 
 
 def round_down(x: float) -> float:
@@ -95,8 +100,9 @@ def main(argv=None) -> int:
                      for n in check.NUMBERS},
         "seeds": {"program": n_prog, **n_var},
         "rule": "limit = lower^0.4 x upper^0.6, rounded down to two digits;"
-                " upper = least reading of the bf16 control or of a state"
-                " left unchanged at >= 3x the lower reading, or of another"
+                " upper = least reading of the bf16 control, of a state"
+                " left unchanged or of a wire fault that keeps no residual"
+                " or zeroes one at >= 3x the lower reading, or of another"
                 " planted fault at >= 10x",
     }
     path = os.path.join(harness.BENCH, "limits", args.workload + ".json")

@@ -5,15 +5,28 @@ from __future__ import annotations
 
 
 def round_flops(cfg: dict, ref, traffic: dict) -> float:
-    """Model FLOPs of one aggregation round: forward and backward (three
-    forward passes' worth) for every local sample of the round's ``k``
+    """Model FLOPs of one aggregation round: the configuration's training
+    step (``flops_train``) for every local sample of the round's ``k``
     uploads, plus one forward pass per evaluation sample.  Recomputed
     operations do not count."""
     eng, pop = traffic["engine"], traffic["population"]
-    fwd = ref.flops_forward(cfg)
-    train = (3.0 * fwd * int(eng["k"]) * int(pop["samples_per_client"])
-             * int(eng["local_epochs"]))
-    return train + fwd * int(traffic["eval_samples"])
+    train = (ref.flops_train(cfg) * int(eng["k"])
+             * int(pop["samples_per_client"]) * int(eng["local_epochs"]))
+    return train + ref.flops_forward(cfg) * int(traffic["eval_samples"])
+
+
+def row_length(cfg: dict, ref) -> int:
+    """Elements of the uploaded row: the trained part of ``init``'s
+    weights (``params``; not the state, not a frozen part), from shapes
+    alone (nothing is allocated)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32)
+    params = jax.eval_shape(lambda k: ref.init(cfg, k), key)[0]
+    return sum(int(np.prod(a.shape))
+               for a in jax.tree_util.tree_leaves(params))
 
 
 def fold_bytes(d: int, wire: str, qblock: int) -> int:

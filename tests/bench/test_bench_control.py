@@ -24,8 +24,8 @@ def test_control_and_planted_faults_fail_the_program_passes():
         assert not check.verdict(out[variant], limits)[0], (variant,
                                                             out[variant])
         # and by a wide margin on the number that catches it
-        worst = max(out[variant][n] / limits["limits"][n]
-                    for n in check.NUMBERS)
+        worst = max(out[variant][n] / lim
+                    for n, lim in limits["limits"].items())
         assert worst > 10, (variant, out[variant])
 
 
@@ -65,3 +65,18 @@ def test_limits_skip_lines_without_a_number():
     d = setlimits.derive([old])
     assert d["limits"]["drift_med"] is None
     assert d["limits"]["loss"] is not None
+
+
+def test_wire_faults_count_at_three_times():
+    """A wire fault that keeps no residual reads 1 by construction: it
+    sets the upper reading from three times the lower one, as a state
+    left unchanged does, while a generic fault needs ten."""
+    import setlimits
+
+    lines = [_line(1, 0.1, drop_half=0.9, no_ef=1.0)]
+    d = setlimits.derive(lines)
+    assert d["upper"]["residual"] == {"value": 1.0, "from": "no_ef"}
+    assert d["limits"]["residual"] == setlimits.round_down(
+        0.1 ** 0.4) == 0.39
+    assert setlimits.derive([_line(1, 0.4, no_ef=1.0)])["limits"][
+        "residual"] is None

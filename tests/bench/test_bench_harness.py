@@ -32,10 +32,16 @@ def test_cell_files_found_by_name(name):
     assert cfg_entry["file"] == f"bench/configs/{cfg_entry['name']}.json"
     assert cell["cfg"]["name"] == cfg_entry["name"]
     assert set(cfg_entry["reduced"]) <= set(cell["cfg"]["published"])
-    for fn in ("init", "apply", "flops_forward"):
+    for fn in ("make_data", "init", "apply", "flops_forward",
+               "flops_train"):
         assert callable(getattr(cell["ref"], fn))
     assert cell["limits"] is not None, "no limits file for " + name
-    assert set(cell["limits"]["limits"]) == set(check.NUMBERS)
+    # ``residual`` is read only where the wire keeps error-feedback
+    # residuals
+    eng = cell["traffic"]["engine"]
+    keeps = eng.get("error_feedback") and eng.get("wire", "f32") != "f32"
+    assert set(cell["limits"]["limits"]) == set(check.NUMBERS) - (
+        set() if keeps else {"residual"})
     for key in ("engine", "population", "eval_samples", "checked_rounds"):
         assert key in cell["traffic"]
 
@@ -107,7 +113,9 @@ def test_last_line_at_tiny_size(trace, capsys):
         assert f"compared {name} {m['value']!r} limit {m['limit']!r}" \
             in out.err
     assert out.err.strip().splitlines()[-1].startswith(
-        f"compared {check.NUMBERS[-1]} ")
+        f"compared {list(line['compared'])[-1]} ")
+    assert list(line["compared"])[1:] == [
+        n for n in check.NUMBERS if n != "residual"]
     assert "0 programs compiled inside the window" in out.err
     if trace:
         assert {"busy_s", "window_s"} <= set(line["device"])
