@@ -51,6 +51,7 @@ def reduced_config(cfg: ModelConfig) -> ModelConfig:
                   n_prefix_tokens=8 if cfg.family == "vlm" else 0)
     elif cfg.family == "moe":
         kw.update(n_layers=2, d_ff=128, n_experts=4, top_k=2,
+                  d_ff_dense=256 if cfg.d_ff_dense else 0,
                   moe_group_size=64,
                   first_k_dense=1 if cfg.first_k_dense else 0,
                   n_shared_experts=min(cfg.n_shared_experts, 1))

@@ -78,6 +78,15 @@ class ModelConfig:
     attn_chunk: int = 0  # 0 -> naive full-matrix attention; >0 -> q-chunked
     attn_impl: str = "chunked"  # chunked | online (flash-style, §Perf)
     attn_kv_chunk: int = 1_024  # kv tile for attn_impl="online"
+    # multi-head latent attention (DeepSeek-V2/V3): attn_type="mla"
+    # projects a kv_lora_rank latent (RMS-normed), expands it per head
+    # to qk_nope_head_dim keys and v_head_dim values, and adds one
+    # qk_rope_head_dim rope key shared by every head (no q latent)
+    attn_type: str = "gqa"  # gqa | mla
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # --- MoE ---
     n_experts: int = 0
@@ -86,6 +95,18 @@ class ModelConfig:
     capacity_factor: float = 1.25
     moe_group_size: int = 1_024
     first_k_dense: int = 0  # leading dense layers before the MoE stack
+    d_ff_dense: int = 0  # width of those dense layers (0 -> d_ff)
+    # router: "softmax" (top-k of softmax, capacity-dropped, aux loss) |
+    # "sigmoid" (DeepSeek-V3 noaux_tc: top-k of sigmoid scores plus a
+    # selection-only bias, chosen scores normalised and scaled by
+    # routed_scaling_factor, no capacity, nothing dropped)
+    router: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    # expert parallelism: the layer routes over all n_experts and holds
+    # (computes) only experts [held_expert_offset, + n_experts_held);
+    # 0 holds every expert
+    n_experts_held: int = 0
+    held_expert_offset: int = 0
     moe_dispatch_dtype: str = "float32"  # bf16 halves dispatch traffic
     moe_dispatch_impl: str = "einsum"  # einsum (GShard) | scatter (§Perf)
 
@@ -120,11 +141,25 @@ class ModelConfig:
     remat: bool = True
     scan_layers: bool = True
     source: str = ""  # citation for the assignment row
+    # low-rank adapters y = x W + (lora_alpha / lora_rank) (x A) B on the
+    # attention projections named in lora_targets (0 = none)
+    lora_rank: int = 0
+    lora_alpha: float = 0.0
+    lora_targets: Tuple[str, ...] = ()
 
     # ------------------------------------------------------------------
     @property
     def hd(self) -> int:
         return self.head_dim or (self.d_model // self.n_heads)
+
+    @property
+    def dense_ff(self) -> int:
+        """Width of a dense MLP layer (the experts' is ``d_ff``)."""
+        return self.d_ff_dense or self.d_ff
+
+    @property
+    def held_experts(self) -> int:
+        return self.n_experts_held or self.n_experts
 
     @property
     def padded_vocab(self) -> int:
@@ -159,6 +194,15 @@ class ModelConfig:
             assert self.n_heads % self.n_kv_heads == 0
         if self.family == "moe":
             assert self.n_experts > 0 and self.top_k > 0
+            assert self.router in ("softmax", "sigmoid"), self.router
+            assert (0 <= self.held_expert_offset and self.held_expert_offset
+                    + self.held_experts <= self.n_experts)
+            if self.router == "softmax":  # capacity dispatch holds all
+                assert self.held_experts == self.n_experts
+        assert self.attn_type in ("gqa", "mla"), self.attn_type
+        if self.attn_type == "mla":
+            assert (self.kv_lora_rank and self.qk_nope_head_dim
+                    and self.qk_rope_head_dim and self.v_head_dim)
         if self.family == "ssm":
             assert self.block_pattern, "ssm family needs a block pattern"
         if self.family == "hybrid":

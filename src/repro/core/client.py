@@ -64,17 +64,36 @@ def sequence_loss(logits, targets, mask=None):
     return jnp.mean(nll)
 
 
-def make_loss_fn(apply_fn: Callable, kind: str):
-    """kind: image | char | sentiment.  batch = (x, y, mask)."""
+def _apply(apply_fn: Callable, params, model_state, x, train: bool,
+           frozen):
+    """``apply_fn``'s forward pass -> ``(logits, state, *report)``; a
+    frozen part of the model, where there is one, goes in as the
+    ``frozen`` keyword.  A model may return a third output, a dict of
+    counters of the pass (``report``), which evaluation hands on."""
+    if frozen is None:
+        return apply_fn(params, model_state, x, train)
+    return apply_fn(params, model_state, x, train, frozen=frozen)
 
-    def loss(params, model_state, x, y, mask):
-        logits, new_state = apply_fn(params, model_state, x, True)
+
+def make_loss_fn(apply_fn: Callable, kind: str):
+    """kind: image | char | sentiment | token.  batch = (x, y, mask).
+
+    ``token``: ``y`` holds one target per position, already shifted (the
+    next token sits at its input's position); a sample's mask covers all
+    its positions."""
+
+    def loss(params, model_state, x, y, mask, frozen=None):
+        logits, new_state, *_ = _apply(apply_fn, params, model_state, x,
+                                       True, frozen)
         if kind == "char":
             # next-char prediction: shift by one
             per = sequence_loss(logits[:, :-1], y[:, 1:],
                                 mask[:, None] * jnp.ones_like(
                                     y[:, 1:], jnp.float32))
             return per, new_state
+        if kind == "token":
+            return sequence_loss(logits, y, jnp.broadcast_to(
+                mask[:, None], y.shape)), new_state
         per_ex = sequence_loss(logits, y, mask)
         return per_ex, new_state
 
@@ -90,11 +109,11 @@ def _make_epoch_body(apply_fn: Callable, kind: str):
     loss_fn = make_loss_fn(apply_fn, kind)
     vg = jax.value_and_grad(loss_fn, has_aux=True)
 
-    def epoch(params, model_state, xs, ys, mask, lr):
+    def epoch(params, model_state, xs, ys, mask, lr, frozen=None):
         def step(carry, batch):
             p, s = carry
             x, y, m = batch
-            (l, s2), g = vg(p, s, x, y, m)
+            (l, s2), g = vg(p, s, x, y, m, frozen)
             any_valid = jnp.sum(m) > 0
             p = jax.tree_util.tree_map(
                 lambda a, b: jnp.where(any_valid, a - lr * b, a), p, g)
@@ -111,10 +130,12 @@ def _make_epoch_body(apply_fn: Callable, kind: str):
 
 
 def make_local_train(apply_fn: Callable, kind: str):
-    """Returns jitted ``epoch(params, state, xs, ys, mask, lr)``.
+    """Returns jitted ``epoch(params, state, xs, ys, mask, lr[, frozen])``.
 
     xs: (n_batches, B, ...); ys likewise; mask (n_batches, B) marks real
-    samples (padding batches have mask 0 and are no-ops).
+    samples (padding batches have mask 0 and are no-ops).  ``frozen``:
+    the model's frozen part, if it has one (an argument, never a
+    constant of the program).
     Returns (params', state', mean_loss).
 
     Memoized on (apply_fn, kind) so multiple engines over the same model
@@ -154,14 +175,14 @@ def make_batched_local_train(apply_fn: Callable, kind: str,
     from repro.sharding.flat import constrain_rows
 
     @jax.jit
-    def round_fn(params, model_state, xs_k, ys_k, mask_k, lr):
+    def round_fn(params, model_state, xs_k, ys_k, mask_k, lr, frozen=None):
         xs_k, ys_k, mask_k = constrain_rows((xs_k, ys_k, mask_k), mesh)
 
-        def per_client(xs, ys, mask):
+        def per_client(xs, ys, mask, frozen):
             p, s = params, model_state
             loss = jnp.float32(0.0)
             for _ in range(local_epochs):
-                p, s, loss = epoch(p, s, xs, ys, mask, lr)
+                p, s, loss = epoch(p, s, xs, ys, mask, lr, frozen)
             if target == "grad":
                 leaves0 = jax.tree_util.tree_leaves(params)
                 leaves1 = jax.tree_util.tree_leaves(p)
@@ -175,7 +196,9 @@ def make_batched_local_train(apply_fn: Callable, kind: str,
                      for l in jax.tree_util.tree_leaves(p)])
             return vec, s, loss
 
-        vecs, states, losses = jax.vmap(per_client)(xs_k, ys_k, mask_k)
+        # the frozen part is one unbatched argument of every lane
+        vecs, states, losses = jax.vmap(
+            per_client, in_axes=(0, 0, 0, None))(xs_k, ys_k, mask_k, frozen)
         return constrain_rows(vecs, mesh), states, losses
 
     _FN_CACHE[key] = round_fn
@@ -190,7 +213,7 @@ def _codec_key(codec) -> tuple:
 
 
 def model_has_conv(apply_fn: Callable, params: Pytree, model_state: Pytree,
-                   sample_x) -> bool:
+                   sample_x, frozen: Pytree = None) -> bool:
     """True iff ``apply_fn``'s forward pass traces a convolution.
 
     The heterogeneous-params vmap lowers convolutions to *grouped*
@@ -203,8 +226,8 @@ def model_has_conv(apply_fn: Callable, params: Pytree, model_state: Pytree,
         return _FN_CACHE[key]
     try:
         jaxpr = jax.make_jaxpr(
-            lambda p, s, x: apply_fn(p, s, x, True))(params, model_state,
-                                                     sample_x)
+            lambda p, s, x, f: _apply(apply_fn, p, s, x, True, f))(
+                params, model_state, sample_x, frozen)
         has = "conv_general_dilated" in str(jaxpr)
     except Exception:  # unusual apply signature: assume the common case
         has = False
@@ -213,7 +236,8 @@ def model_has_conv(apply_fn: Callable, params: Pytree, model_state: Pytree,
 
 
 def resolve_wave_impl(impl: str, apply_fn: Callable, params: Pytree,
-                      model_state: Pytree, sample_x) -> str:
+                      model_state: Pytree, sample_x,
+                      frozen: Pytree = None) -> str:
     """Resolve ``FLConfig.wave_impl``: "vmap" / "map" pass through;
     "auto" keeps the vmapped wave except for conv models on CPU, where
     the grouped-convolution lowering loses to one serial-wave dispatch
@@ -224,7 +248,7 @@ def resolve_wave_impl(impl: str, apply_fn: Callable, params: Pytree,
     if jax.default_backend() != "cpu":
         return "vmap"  # grouped convs are native on TPU/GPU
     return ("map" if model_has_conv(apply_fn, params, model_state,
-                                    sample_x) else "vmap")
+                                    sample_x, frozen) else "vmap")
 
 
 def make_batched_hetero_train(apply_fn: Callable, kind: str, target: str,
@@ -280,11 +304,11 @@ def make_batched_hetero_train(apply_fn: Callable, kind: str, target: str,
     unravel, ravel = codec.unravel_fn, codec.ravel_fn
     from repro.sharding.flat import constrain_rows
 
-    def per_client(flat, state, xs, ys, mask, lr):
+    def per_client(flat, state, xs, ys, mask, lr, frozen):
         p, s = unravel(flat), state
         loss = jnp.float32(0.0)
         for _ in range(local_epochs):
-            p, s, loss = epoch(p, s, xs, ys, mask, lr)
+            p, s, loss = epoch(p, s, xs, ys, mask, lr, frozen)
         new_flat = ravel(p)
         if target == "grad":
             vec = (flat - new_flat) / lr
@@ -293,13 +317,17 @@ def make_batched_hetero_train(apply_fn: Callable, kind: str, target: str,
         return vec, new_flat, s, loss
 
     @jax.jit
-    def round_fn(flat_k, states_k, xs_all, ys_all, mask_all, idx, lr):
+    def round_fn(flat_k, states_k, xs_all, ys_all, mask_all, idx, lr,
+                 frozen=None):
         lanes = (flat_k, states_k, xs_all[idx], ys_all[idx], mask_all[idx])
         if impl == "map":
-            return jax.lax.map(lambda a: per_client(*a, lr), lanes)
+            return jax.lax.map(lambda a: per_client(*a, lr, frozen), lanes)
         lanes = constrain_rows(lanes, mesh)
+        # the frozen part is one unbatched argument of every lane: never
+        # broadcast per lane, never a constant of the program
         vecs, new_flat, states, losses = jax.vmap(
-            lambda f, st, x, y, m: per_client(f, st, x, y, m, lr))(*lanes)
+            lambda f, st, x, y, m, fr: per_client(f, st, x, y, m, lr, fr),
+            in_axes=(0, 0, 0, 0, 0, None))(*lanes, frozen)
         # only the upload rows stay pod-sharded (they feed the sharded
         # buffer scatter + podwise reduction); new_flat is host-side
         # client state, indexed row-wise at refresh — pinning it would
@@ -378,8 +406,12 @@ def cumulative_gradient(w_start: Pytree, w_end: Pytree, lr: float) -> Pytree:
 
 
 def _make_eval_body(apply_fn: Callable, kind: str):
-    def evaluate(params, model_state, x, y):
-        logits, _ = apply_fn(params, model_state, x, False)
+    """``evaluate(params, state, x, y[, frozen]) -> (accuracy, loss,
+    *report)``; for ``token`` both are over every position.  ``report``:
+    the counters dict a model returns as its third output, if it does."""
+    def evaluate(params, model_state, x, y, frozen=None):
+        logits, _, *report = _apply(apply_fn, params, model_state, x, False,
+                                    frozen)
         if kind == "char":
             pred = jnp.argmax(logits[:, :-1], axis=-1)
             tgt = y[:, 1:]
@@ -389,7 +421,7 @@ def _make_eval_body(apply_fn: Callable, kind: str):
             pred = jnp.argmax(logits, axis=-1)
             acc = jnp.mean((pred == y).astype(jnp.float32))
             loss = sequence_loss(logits, y)
-        return acc, loss
+        return (acc, loss, *report)
 
     return evaluate
 
@@ -404,16 +436,18 @@ def make_eval_fn(apply_fn: Callable, kind: str):
 
 
 def make_flat_eval_fn(apply_fn: Callable, kind: str, codec):
-    """``evaluate(flat_params, state, x, y)`` with the unravel fused into
-    the jitted program — the batched engine keeps the global model as a
-    flat (D,) row end-to-end and never materializes the pytree per eval."""
+    """``evaluate(flat_params, state, x, y[, frozen])`` with the unravel
+    fused into the jitted program — the batched engine keeps the global
+    model as a flat (D,) row end-to-end and never materializes the
+    pytree per eval."""
     key = ("eval_flat", apply_fn, kind, _codec_key(codec))
     if key in _FN_CACHE:
         return _FN_CACHE[key]
     body = _make_eval_body(apply_fn, kind)
     unravel = codec.unravel_fn
     evaluate = jax.jit(
-        lambda flat, state, x, y: body(unravel(flat), state, x, y))
+        lambda flat, state, x, y, frozen=None: body(unravel(flat), state, x,
+                                                    y, frozen))
     _FN_CACHE[key] = evaluate
     return evaluate
 

@@ -184,6 +184,9 @@ class RoundRecord:
     # uploads dropped by the screen and influence-clipped by the norm cap
     screened_uploads: int = 0
     clipped_uploads: int = 0
+    # the counters the model reported from this round's evaluation, by
+    # name (empty for a model that reports none)
+    reported: Dict[str, float] = dataclasses.field(default_factory=dict)
 
 
 class MetricsLog:

@@ -124,6 +124,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro import faults as faultsmod
 from repro import sched as schedmod
@@ -162,6 +163,11 @@ _MODEL_TARGETS = ("fedavg", "fedasync")
 _span = jax.profiler.TraceAnnotation
 
 
+def _floats(report) -> Dict[str, float]:
+    """The counters a model reported from an evaluation, as floats."""
+    return {k: float(v) for k, v in report.items()}
+
+
 @dataclasses.dataclass
 class FLResult:
     metrics: MetricsLog
@@ -183,7 +189,14 @@ class FLEngine:
     def __init__(self, fl_cfg, apply_fn: Callable, kind: str,
                  init_params: Pytree, init_state: Pytree,
                  client_shards: Sequence[Dict[str, np.ndarray]],
-                 test_x: np.ndarray, test_y: np.ndarray):
+                 test_x: np.ndarray, test_y: np.ndarray,
+                 frozen: Optional[Pytree] = None):
+        """``frozen``: a part of the model every client reads and none
+        trains (a base model under LoRA adapters, ``apply_fn(...,
+        frozen=)``).  It is held once on the device and handed to the
+        client, wave and evaluation programs as an argument; it is never
+        uploaded, aggregated, snapshotted or copied per client: only
+        ``init_params`` are."""
         fl_cfg.validate()
         self.cfg = fl_cfg
         self.kind = kind
@@ -314,6 +327,12 @@ class FLEngine:
             edges, pods = fl_cfg.mesh_shape or (1, fl_cfg.devices)
             self._mesh = shflat.make_hier_mesh(edges, pods)
             row_sh = shflat.row_sharding(self._mesh)
+        # the frozen part, once on the device (replicated over a mesh);
+        # the trailing argument of every client program that reads it
+        self.frozen = None if frozen is None else jax.device_put(
+            frozen, None if self._mesh is None else NamedSharding(
+                self._mesh, PartitionSpec()))
+        self._fz = () if frozen is None else (self.frozen,)
         # discount-at-ingest: the engine composes the FINAL per-upload
         # aggregation weights on host for EVERY mode (_weight_vector) —
         # the (1+tau)^-alpha discount, fedavg data sizes, adaptive policy
@@ -481,7 +500,7 @@ class FLEngine:
         for _ in range(self.cfg.local_epochs):
             params, state, loss = self.epoch_fn(
                 params, state, shard["xs"], shard["ys"], shard["mask"],
-                self.cfg.client_lr)
+                self.cfg.client_lr, *self._fz)
         return params, state, loss
 
     # ------------------------------------------------------------------
@@ -863,11 +882,13 @@ class FLEngine:
         return rnd % self.cfg.eval_every == 0 or rnd == n_rounds
 
     def _eval_and_record(self, now: float, stale_vals: Sequence[int]) -> None:
-        acc, loss = self.eval_fn(self.global_params, self.global_state,
-                                 self.test_x, self.test_y)
+        acc, loss, *report = self.eval_fn(self.global_params,
+                                          self.global_state, self.test_x,
+                                          self.test_y, *self._fz)
         acc, loss = float(acc), float(loss)
         nan_event = not np.isfinite(loss)
         self.metrics.record(
+            reported=_floats(report[0] if report else {}),
             round=self.t_global, sim_time=now, accuracy=acc, loss=loss,
             tx_bytes=self.tx_bytes, rx_bytes=self.rx_bytes,
             mean_staleness=float(np.mean(stale_vals)) if stale_vals else 0.0,
@@ -963,7 +984,7 @@ class FLEngine:
                                    for cid in active])
                 vecs, states_k, _losses = round_fn(
                     self.global_params, self.global_state, xs_k, ys_k,
-                    mask_k, cfg.client_lr)
+                    mask_k, cfg.client_lr, *self._fz)
                 if target == "params":
                     # the server sees the int8-shipped state roundtrip
                     # (identity on the f32 channel)
@@ -1153,7 +1174,7 @@ class FLEngine:
         if self.wave_impl_resolved is None:
             self.wave_impl_resolved = resolve_wave_impl(
                 cfg.wave_impl, self.apply_fn, self.global_params,
-                self.global_state, self.test_x[:1])
+                self.global_state, self.test_x[:1], self.frozen)
         wave_fn = make_batched_hetero_train(
             self.apply_fn, self.kind, target, cfg.local_epochs, self.codec,
             impl=self.wave_impl_resolved, mesh=self._mesh)
@@ -1185,6 +1206,9 @@ class FLEngine:
                                      stale_bins=_STALE_BINS,
                                      n_clients=len(self.clients))
         pending: List[Dict] = []  # host-side fields per recorded round
+        # per recorded round, the counters the model reports from its
+        # evaluation (models that report any; fetched with the ring)
+        reports: List[Dict[str, jax.Array]] = []
 
         # model-state leaves per client (the span stat `leaves`; 0 for a
         # model without BatchNorm)
@@ -1332,7 +1356,7 @@ class FLEngine:
                         with _span("safl.train"):
                             vecs, new_flat, new_states, _losses = wave_fn(
                                 starts, states, xs_all, ys_all, mask_all,
-                                jnp.asarray(cids), cfg.client_lr)
+                                jnp.asarray(cids), cfg.client_lr, *self._fz)
                         with _span("safl.encode"):
                             prows = self._encode_wave(
                                 vecs, members, cids, evfaults, hfac, use_ef)
@@ -1461,9 +1485,11 @@ class FLEngine:
                 with _span("safl.eval"):
                     rnd = self.t_global
                     if self._eval_due(rnd, n_rounds):
-                        acc, loss = eval_fn(self._flat_params,
-                                            self.global_state,
-                                            self.test_x, self.test_y)
+                        acc, loss, *report = eval_fn(self._flat_params,
+                                                     self.global_state,
+                                                     self.test_x,
+                                                     self.test_y, *self._fz)
+                        reports += report
                         ring.append(acc, loss, m["update_norm"],
                                     np.float32(self.screened_uploads),
                                     np.float32(self.clipped_uploads))
@@ -1482,9 +1508,12 @@ class FLEngine:
 
         # ---- the ONE device->host metrics transfer of the run ----
         with _span("safl.flush"):
-            for fields, (acc, loss, unorm, nscr, nclip) in zip(
-                    pending, ring.flush()):
+            got = (jax.device_get(reports) if reports
+                   else [{}] * len(pending))
+            for fields, (acc, loss, unorm, nscr, nclip), rep in zip(
+                    pending, ring.flush(), got):
                 self.metrics.record(
+                    reported=_floats(rep),
                     accuracy=float(acc), loss=float(loss),
                     nan_event=not np.isfinite(loss),
                     update_norm=float(unorm),

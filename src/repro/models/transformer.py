@@ -19,12 +19,14 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import importlib
 from typing import Any, Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 
-from repro.models import layers, moe as moe_lib, ssm as ssm_lib, xlstm
+from repro.models import layers, mla as mla_lib, moe as moe_lib
+from repro.models import ssm as ssm_lib, xlstm
 from repro.sharding.ctx import constrain_batch
 
 
@@ -36,6 +38,9 @@ class Model:
     prefill: Callable  # (params, batch) -> (logits_last, cache)
     decode_step: Callable  # (params, cache, tokens(B,), pos) -> (logits, cache)
     param_count: Callable
+    # decoder LMs: (params, batch, adapters=None, load=False) ->
+    # (logits, aux[, held experts' load per token, summed over layers])
+    forward: Optional[Callable] = None
 
 
 def _dtype(cfg):
@@ -65,38 +70,53 @@ def _maybe_remat(fn, cfg):
 def _decoder_layer_init(cfg, dtype, use_moe: bool):
     def init(key):
         k1, k2 = jax.random.split(key)
+        mla = cfg.attn_type == "mla"
         p = {
             "ln1": layers.rmsnorm_init(cfg.d_model, dtype),
-            "attn": layers.attention_init(k1, cfg, dtype),
+            "attn": (mla_lib.mla_init(k1, cfg, dtype) if mla
+                     else layers.attention_init(k1, cfg, dtype)),
             "ln2": layers.rmsnorm_init(cfg.d_model, dtype),
         }
         if use_moe:
             p["moe"] = moe_lib.moe_init(k2, cfg, dtype)
         else:
-            p["mlp"] = layers.mlp_init(k2, cfg, dtype)
+            p["mlp"] = layers.mlp_init(k2, cfg, dtype, d_ff=cfg.dense_ff)
         return p
     return init
 
 
 def _decoder_layer_apply(p, cfg, x, positions, window, use_moe: bool,
-                         return_kv: bool = False):
+                         return_kv: bool = False, lora=None,
+                         load: bool = False):
+    """One pre-norm block -> (x, aux), or (x, aux, kv) with
+    ``return_kv``, or (x, aux, held experts' load) with ``load`` (MoE
+    layers of a sigmoid router).  ``lora``: this layer's adapters."""
     x = constrain_batch(x)
     h = layers.rmsnorm(p["ln1"], x, cfg.norm_eps)
-    attn = layers.full_attention(p["attn"], cfg, h, positions, window=window,
-                                 return_kv=return_kv)
     kv = None
-    if return_kv:
-        attn, kv = attn
+    if cfg.attn_type == "mla":
+        if return_kv:
+            raise NotImplementedError("no KV cache for latent attention")
+        attn = mla_lib.mla_attention(p["attn"], cfg, h, positions, lora)
+    else:
+        attn = layers.full_attention(p["attn"], cfg, h, positions,
+                                     window=window, return_kv=return_kv)
+        if return_kv:
+            attn, kv = attn
     x = x + attn
     h = layers.rmsnorm(p["ln2"], x, cfg.norm_eps)
     aux = jnp.zeros((), jnp.float32)
-    if use_moe:
+    if use_moe and load:
+        y, aux, ld = moe_lib.moe_apply_dropless(p["moe"], cfg, h, load=True)
+    elif use_moe:
         y, aux = moe_lib.moe_apply(p["moe"], cfg, h)
     else:
         y = layers.mlp(p["mlp"], cfg, h)
     out = constrain_batch(x + y)
     if return_kv:
         return out, aux, kv
+    if use_moe and load:
+        return out, aux, ld
     return out, aux
 
 
@@ -154,34 +174,47 @@ def _build_decoder_lm(cfg):
             x = jnp.concatenate([pre, x], axis=1)
         return x
 
-    def _stack(params, x, positions, window):
-        """Run all layers via scan; returns (x, aux_sum)."""
+    def _stack(params, x, positions, window, adapters=None, load=False):
+        """Run all layers via scan; returns (x, aux_sum), plus the held
+        experts' load summed over the MoE layers with ``load``.
+        ``adapters``: LoRA adapters stacked like ``params``' layers."""
         aux_tot = jnp.zeros((), jnp.float32)
+        ld_tot = jnp.zeros((cfg.held_experts,), jnp.float32)
         for name, use_moe in (("layers_dense", False), ("layers_moe", True)):
             if name not in params:
                 continue
+            want = load and use_moe
+            ad = adapters[name] if adapters is not None else None
             body = _maybe_remat(
-                lambda carry, lp, um=use_moe: _decoder_layer_apply(
-                    lp, cfg, carry, positions, window, um), cfg)
+                lambda carry, xs, um=use_moe, want=want:
+                _decoder_layer_apply(xs[0], cfg, carry, positions, window,
+                                     um, lora=xs[1], load=want), cfg)
 
-            def scan_fn(carry, lp):
-                x, aux = carry
-                x, a = body(x, lp)
-                return (x, aux + a), None
+            def scan_fn(carry, xs, body=body, want=want):
+                x, aux, ld = carry
+                if want:
+                    x, a, l = body(x, xs)
+                    ld = ld + l
+                else:
+                    x, a = body(x, xs)
+                return (x, aux + a, ld), None
 
-            (x, aux_tot), _ = jax.lax.scan(scan_fn, (x, aux_tot),
-                                           params[name])
+            (x, aux_tot, ld_tot), _ = jax.lax.scan(
+                scan_fn, (x, aux_tot, ld_tot), (params[name], ad))
+        if load:
+            return x, aux_tot, ld_tot
         return x, aux_tot
 
-    def forward(params, batch, window=None):
+    def forward(params, batch, window=None, adapters=None, load=False):
         x = _embed_inputs(params, batch)
         S = x.shape[1]
         positions = jnp.arange(S, dtype=jnp.int32)
-        x, aux = _stack(params, x, positions, window or cfg.sliding_window)
+        x, aux, *ld = _stack(params, x, positions,
+                             window or cfg.sliding_window, adapters, load)
         x = layers.rmsnorm(params["ln_f"], x, cfg.norm_eps)
         logits = layers.lm_head(params["embed"], params.get("head"), x,
                                 cfg.tie_embeddings)
-        return logits, aux
+        return (logits, aux, *ld)
 
     def train_loss(params, batch):
         logits, aux = forward(params, batch)
@@ -281,7 +314,8 @@ def _build_decoder_lm(cfg):
         return logits[:, 0], cache
 
     return Model(cfg, init, train_loss, prefill, decode_step,
-                 lambda p: sum(x.size for x in jax.tree_util.tree_leaves(p)))
+                 lambda p: sum(x.size for x in jax.tree_util.tree_leaves(p)),
+                 forward=forward)
 
 
 # ===========================================================================
@@ -674,3 +708,72 @@ def build_model(cfg) -> Model:
     if cfg.family == "audio":
         return _build_encdec(cfg)
     raise ValueError(cfg.family)
+
+
+# ===========================================================================
+# federated LoRA fine-tuning of a frozen decoder LM (the FL engine's model)
+# ===========================================================================
+
+
+@functools.lru_cache(maxsize=None)
+def _fl_model(config: str, overrides: tuple) -> Model:
+    cfg = importlib.import_module(f"repro.configs.{config}").CONFIG
+    if overrides:
+        cfg = dataclasses.replace(cfg, **dict(overrides))
+    return build_model(cfg)
+
+
+def fl_model(config: str, **overrides) -> Model:
+    """The decoder LM of ``repro.configs.<config>``, with any
+    ``ModelConfig`` field replaced by ``overrides`` (built once)."""
+    return _fl_model(config, tuple(sorted(overrides.items())))
+
+
+def lora_init(cfg, key) -> dict:
+    """Rank-``cfg.lora_rank`` adapters of every decoder layer, stacked
+    like the base's ``layers_dense`` / ``layers_moe``."""
+    dtype = _dtype(cfg)
+    n_dense = cfg.first_k_dense if cfg.family == "moe" else cfg.n_layers
+    n_moe = cfg.n_layers - n_dense
+    kd, km = jax.random.split(key)
+    init = lambda k: mla_lib.lora_init(k, cfg, dtype)
+    out = {}
+    if n_dense:
+        out["layers_dense"] = _stacked_init(init, kd, n_dense)
+    if n_moe:
+        out["layers_moe"] = _stacked_init(init, km, n_moe)
+    return out
+
+
+def lora_apply(params, state, x, train, *, frozen, config: str,
+               **overrides):
+    """The FL engine's model function for federated LoRA fine-tuning:
+    ``params`` are the adapters (:func:`lora_init`), ``state`` is ``{}``
+    and ``frozen`` is the base model (``build_model(cfg).init``), shared
+    by every client and never trained.  ``x``: token ids (..., seq).
+
+    Returns ``(logits (..., seq, vocab), state, report)``: ``report``
+    holds the counters of an evaluation pass that the engine records on
+    the round (empty in training).  A model with a sigmoid router reports
+    its held experts' load there: ``routed_held``, assignments to a held
+    expert per token and MoE layer (``top_k * held / n_experts`` at a
+    uniform router), and ``routed_max_share``, the largest held expert's
+    share of them."""
+    model = fl_model(config, **overrides)
+    cfg = model.cfg
+    lead = x.shape[:-1]
+    tokens = x.reshape(-1, x.shape[-1])
+    load = (not train and cfg.family == "moe" and cfg.router == "sigmoid"
+            and cfg.n_layers > cfg.first_k_dense)
+    out = model.forward(frozen, {"tokens": tokens}, adapters=params,
+                        load=load)
+    logits = out[0][..., :cfg.vocab_size].reshape(
+        lead + (x.shape[-1], cfg.vocab_size))
+    report = {}
+    if load:
+        per = out[2] / (cfg.n_layers - cfg.first_k_dense)
+        held = jnp.sum(per)
+        report = {"routed_held": held,
+                  "routed_max_share": jnp.max(per) / jnp.maximum(held,
+                                                                 1e-30)}
+    return logits, state, report

@@ -193,6 +193,11 @@ def from_engine(eng, registry: Optional[MetricsRegistry] = None
     reg.gauge("safl_sim_time_seconds",
               "simulated clock at the last horizon close").set(
                   float(eng._last_agg_time))
+    recs = eng.metrics.records
+    for name, value in (recs[-1].reported if recs else {}).items():
+        reg.gauge(f"safl_model_{name}",
+                  f"{name}, as the model reported it from the last "
+                  "evaluation").set(value)
     wall = float(getattr(eng, "wall_run_s", 0.0))
     reg.gauge("safl_wall_run_seconds",
               "wall-clock spent inside FLEngine.run").set(wall)
